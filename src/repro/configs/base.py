@@ -74,7 +74,7 @@ class ArchConfig:
     # tie each slot's weight gathers to the previous slot's output so the
     # scheduler can't hoist every FSDP all-gather to the period top
     # (bounds peak temp to ~one slot's gathered weights; trades away some
-    # gather/compute overlap — see EXPERIMENTS.md §Perf)
+    # gather/compute overlap)
     serialize_slot_gathers: bool = False
 
     # modality

@@ -1,6 +1,10 @@
 """Blocked (FlashAttention-style) attention Pallas kernel for TPU.
 
 TPU-native design, not a CUDA port:
+  * heads-major layout (B, H, S, D): every block's last two dims are
+    (block, D), which meets the TPU's (8, 128) tiling rule — a BSHD
+    block would squeeze the head axis into the second-minor position.
+    ``ops.flash_attention`` transposes from the public BSHD layout.
   * grid = (B, Hq, Sq/bq, Skv/bk) with the KV axis innermost — the TPU
     grid is executed sequentially over the minor axis, so the online
     softmax state (m, l, acc) lives in VMEM scratch and is carried
@@ -96,9 +100,9 @@ def flash_attention_kernel_call(q: jnp.ndarray, k: jnp.ndarray,
                                 block_q: int = 128,
                                 block_k: int = 128,
                                 interpret: bool = False) -> jnp.ndarray:
-    """q: (B, Sq, Hq, D); k, v: (B, Skv, Hkv, D). Returns (B, Sq, Hq, D)."""
-    B, Sq, Hq, D = q.shape
-    _, Skv, Hkv, _ = k.shape
+    """q: (B, Hq, Sq, D); k, v: (B, Hkv, Skv, D). Returns (B, Hq, Sq, D)."""
+    B, Hq, Sq, D = q.shape
+    _, Hkv, Skv, _ = k.shape
     if Hq % Hkv:
         raise ValueError(f"Hq={Hq} % Hkv={Hkv} != 0")
     group = Hq // Hkv
@@ -115,18 +119,18 @@ def flash_attention_kernel_call(q: jnp.ndarray, k: jnp.ndarray,
         kv_offset=kv_offset, block_q=block_q, block_k=block_k)
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((B, Sq, Hq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, block_q, None, D),
-                         lambda b, h, qi, ki: (b, qi, h, 0)),
-            pl.BlockSpec((None, block_k, None, D),
-                         lambda b, h, qi, ki: (b, ki, h // group, 0)),
-            pl.BlockSpec((None, block_k, None, D),
-                         lambda b, h, qi, ki: (b, ki, h // group, 0)),
+            pl.BlockSpec((None, None, block_q, D),
+                         lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((None, None, block_k, D),
+                         lambda b, h, qi, ki: (b, h // group, ki, 0)),
+            pl.BlockSpec((None, None, block_k, D),
+                         lambda b, h, qi, ki: (b, h // group, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((None, block_q, None, D),
-                               lambda b, h, qi, ki: (b, qi, h, 0)),
+        out_specs=pl.BlockSpec((None, None, block_q, D),
+                               lambda b, h, qi, ki: (b, h, qi, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),   # running max m
             pltpu.VMEM((block_q, 1), jnp.float32),   # running denom l

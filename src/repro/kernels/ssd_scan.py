@@ -14,7 +14,12 @@ splits into (i) dense intra-chunk matmuls that run on the MXU and
     (C·Bᵀ ⊙ decay) (L×L), its product with X (L×P), and the chunk-state
     update Bᵀ·(decay ⊙ X) (N×P). L defaults to 128 for MXU alignment.
   * the decay matrix uses the log-cumsum-exp trick in f32; per-head
-    scalar decays (Mamba2) keep it rank-1 — exp(Acum_i − Acum_j).
+    scalar decays (Mamba2) keep it rank-1 — exp(Acum_i − Acum_j). The
+    in-chunk cumsum is a lower-triangular ones matmul (Mosaic has no
+    cumsum lowering).
+  * heads-major layouts — x (B, H, S, P), a (B, H, S, 1), b/c
+    (B, G, S, N) — so every block's last two dims meet the TPU's (8, 128)
+    tiling rule; ``ops.ssd_scan`` transposes from the public layout.
 
 Oracle: :func:`repro.kernels.ref.ssd_ref` (sequential scan).
 """
@@ -40,22 +45,29 @@ def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, state_ref, *,
         state_ref[...] = jnp.zeros_like(state_ref)
 
     x = x_ref[...].astype(jnp.float32)          # (L, P)
-    a = a_ref[...].astype(jnp.float32)          # (L,)
+    a = a_ref[...].astype(jnp.float32)          # (L, 1)
     b = b_ref[...].astype(jnp.float32)          # (L, N)
     c = c_ref[...].astype(jnp.float32)          # (L, N)
 
-    acum = jnp.cumsum(a)                        # inclusive: A_t = Σ_{s<=t} a_s
-    a_tot = acum[-1]
+    # inclusive cumsum A_t = Σ_{s<=t} a_s, as a column and as a row
+    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tri = row >= col
+    exact = jax.lax.Precision.HIGHEST
+    acum = jax.lax.dot(tri.astype(jnp.float32), a,
+                       precision=exact)                          # (L, 1)
+    acum_row = jax.lax.dot_general(
+        a, (row <= col).astype(jnp.float32),
+        (((0,), (0,)), ((), ())), precision=exact)               # (1, L)
+    a_tot = jnp.sum(a, axis=0, keepdims=True)                    # (1, 1)
 
     # --- carried-state contribution: y_inter[t] = exp(A_t)·C_t·h0
     h0 = state_ref[...]                         # (N, P)
-    y_inter = jnp.exp(acum)[:, None] * jax.lax.dot(c, h0)        # (L, P)
+    y_inter = jnp.exp(acum) * jax.lax.dot(c, h0)                 # (L, P)
 
     # --- intra-chunk (dual/attention-like) term, causal within the chunk:
     # scores[t, s] = (C_t·B_s)·exp(A_t − A_s) for s ≤ t
-    logdecay = acum[:, None] - acum[None, :]                     # (L, L)
-    tri = jax.lax.iota(jnp.int32, chunk)[:, None] >= \
-        jax.lax.iota(jnp.int32, chunk)[None, :]
+    logdecay = acum - acum_row                                   # (L, L)
     # mask before exp: upper-triangle logdecay is positive (overflow risk)
     decay = jnp.exp(jnp.where(tri, logdecay, -jnp.inf))
     scores = jax.lax.dot_general(c, b, (((1,), (1,)), ((), ()))) * decay
@@ -63,7 +75,7 @@ def _kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, state_ref, *,
     y_ref[...] = y.astype(y_ref.dtype)
 
     # --- state update: h' = exp(A_tot)·h0 + Σ_s exp(A_tot − A_s)·B_s ⊗ x_s
-    w = jnp.exp(a_tot - acum)[:, None] * b                       # (L, N)
+    w = jnp.exp(a_tot - acum) * b                                # (L, N)
     state_ref[...] = jnp.exp(a_tot) * h0 + \
         jax.lax.dot_general(w, x, (((0,), (0,)), ((), ())))      # (N, P)
 
@@ -76,13 +88,12 @@ def ssd_scan_kernel_call(x: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
                          c: jnp.ndarray,
                          chunk: int = 128,
                          interpret: bool = False):
-    """x: (B, S, H, P); a: (B, S, H); b, c: (B, S, G, N).
+    """x: (B, H, S, P); a: (B, H, S, 1); b, c: (B, G, S, N).
 
-    Returns (y, final_state): (B, S, H, P), (B, H, N, P) — matching
-    ``ssd_ref(..., return_state=True)`` with h0 = 0.
+    Returns (y, final_state): (B, H, S, P), (B, H, N, P) — with h0 = 0.
     """
-    B, S, H, P = x.shape
-    _, _, G, N = b.shape
+    B, H, S, P = x.shape
+    _, G, _, N = b.shape
     if H % G:
         raise ValueError(f"H={H} % G={G} != 0")
     rep = H // G
@@ -93,22 +104,22 @@ def ssd_scan_kernel_call(x: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
     grid = (B, H, S // chunk)
     y, hT = pl.pallas_call(
         functools.partial(_kernel, chunk=chunk),
-        out_shape=(jax.ShapeDtypeStruct((B, S, H, P), x.dtype),
+        out_shape=(jax.ShapeDtypeStruct((B, H, S, P), x.dtype),
                    jax.ShapeDtypeStruct((B, H, N, P), jnp.float32)),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((None, chunk, None, P),
-                         lambda bb, h, ci: (bb, ci, h, 0)),
-            pl.BlockSpec((None, chunk, None),
-                         lambda bb, h, ci: (bb, ci, h)),
-            pl.BlockSpec((None, chunk, None, N),
-                         lambda bb, h, ci: (bb, ci, h // rep, 0)),
-            pl.BlockSpec((None, chunk, None, N),
-                         lambda bb, h, ci: (bb, ci, h // rep, 0)),
+            pl.BlockSpec((None, None, chunk, P),
+                         lambda bb, h, ci: (bb, h, ci, 0)),
+            pl.BlockSpec((None, None, chunk, 1),
+                         lambda bb, h, ci: (bb, h, ci, 0)),
+            pl.BlockSpec((None, None, chunk, N),
+                         lambda bb, h, ci: (bb, h // rep, ci, 0)),
+            pl.BlockSpec((None, None, chunk, N),
+                         lambda bb, h, ci: (bb, h // rep, ci, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((None, chunk, None, P),
-                         lambda bb, h, ci: (bb, ci, h, 0)),
+            pl.BlockSpec((None, None, chunk, P),
+                         lambda bb, h, ci: (bb, h, ci, 0)),
             pl.BlockSpec((None, None, N, P),
                          lambda bb, h, ci: (bb, h, 0, 0)),
         ),

@@ -2,9 +2,11 @@
 caches, report latency/throughput.
 
 The decode loop is the production shape (jit'd single-token step over a
-static-capacity cache, donated buffers); batch composition is static per
-run (continuous batching would swap finished rows — the cache layout
-already supports per-row lengths via the shared ``length`` counter).
+static-capacity cache); batch composition is static per run (continuous
+batching would swap finished rows — the cache layout already supports
+per-row lengths via the shared ``length`` counter). Both programs are
+compiled ahead of time, so the reported prefill and decode times hold no
+compilation.
 
     PYTHONPATH=src python -m repro.launch.serve --arch mamba2-1.3b \
         --reduced --batch 4 --prompt-len 64 --gen 32
@@ -13,6 +15,7 @@ already supports per-row lengths via the shared ``length`` counter).
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -20,7 +23,17 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.launch.jax_cache import use_persistent_compile_cache
 from repro.models import model as model_lib
+
+
+@dataclasses.dataclass(frozen=True)
+class ServeResult:
+    tokens: np.ndarray          # (B, gen) generated ids
+    prefill_logits: np.ndarray  # (B, 1, V) f32 logits at the last prompt token
+    compile_s: float            # prefill + decode compilation
+    prefill_s: float            # host clock, to the first token
+    decode_s_per_token: float   # host clock, mean over gen - 1 steps
 
 
 def main(argv=None):
@@ -63,7 +76,15 @@ def main(argv=None):
         return nxt[:, None], caches
 
     t0 = time.time()
-    logits, caches = prefill_fn(params, prompts, media)
+    prefill_lo = prefill_fn.lower(params, prompts, media)
+    prefill_c = prefill_lo.compile()
+    tok_sds = jax.ShapeDtypeStruct((B, 1), jnp.int32)
+    decode_c = decode_fn.lower(params, prefill_lo.out_info[1],
+                               tok_sds).compile()
+    t_compile = time.time() - t0
+
+    t0 = time.time()
+    logits, caches = prefill_c(params, prompts, media)
     tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)[:, None]
     jax.block_until_ready(tok)
     t_prefill = time.time() - t0
@@ -71,7 +92,7 @@ def main(argv=None):
     out = [tok]
     t0 = time.time()
     for _ in range(args.gen - 1):
-        tok, caches = decode_fn(params, caches, tok)
+        tok, caches = decode_c(params, caches, tok)
         out.append(tok)
     jax.block_until_ready(tok)
     t_decode = time.time() - t0
@@ -79,13 +100,15 @@ def main(argv=None):
     gen = np.concatenate([np.asarray(t) for t in out], axis=1)
     per_tok = t_decode / max(args.gen - 1, 1)
     print(f"[serve] {cfg.name}: batch={B} prompt={P} gen={args.gen}")
+    print(f"[serve] compile {t_compile:8.1f} s (prefill + decode)")
     print(f"[serve] prefill {t_prefill*1e3:8.1f} ms "
           f"({B*P/t_prefill:9.0f} tok/s)")
     print(f"[serve] decode  {per_tok*1e3:8.2f} ms/tok "
           f"({B/max(per_tok,1e-9):9.0f} tok/s)")
     print(f"[serve] sample row 0: {gen[0][:16].tolist()}")
-    return gen
+    return ServeResult(gen, np.asarray(logits), t_compile, t_prefill, per_tok)
 
 
 if __name__ == "__main__":
+    use_persistent_compile_cache()
     main()

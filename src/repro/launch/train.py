@@ -1,13 +1,15 @@
 """End-to-end training driver.
 
-Production-shaped loop: sharded params/opt-state, grad accumulation,
-checkpoint-every-k with async writes + exact resume (stateless data
-pipeline), straggler monitoring hooks, optional int8-compressed cross-pod
-gradients, and the paper's topology-aware placement (mesh ordering +
-MoE steal tables).
+Production-shaped loop: grad accumulation, checkpoint-every-k with async
+writes + exact resume (stateless data pipeline), straggler monitoring
+hooks, optional int8-compressed cross-pod gradients, and the paper's MoE
+steal tables. The step is jitted for one device; the mesh path is not
+wired in yet.
 
-Runs anywhere: on this CPU container use ``--reduced`` (same code path,
-small model). Example (quickstart uses the same entry):
+``main`` parses arguments and builds the config; :func:`train` runs the
+loop for any ``ArchConfig`` (``chip_smoke.py`` passes a depth-cut one).
+``--reduced`` gives the small same-family config the CPU tests use; the
+published configs are sized for a TPU. Example:
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen2.5-3b \
         --reduced --steps 50 --global-batch 8 --seq-len 128
@@ -20,7 +22,6 @@ import dataclasses
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
@@ -28,7 +29,7 @@ from repro.checkpoint import CheckpointManager
 from repro.core import topology as topo_mod
 from repro.core.routing import expert_steal_table
 from repro.data import PipelineConfig, Prefetcher, TokenPipeline
-from repro.launch import shardings as shd
+from repro.launch.jax_cache import use_persistent_compile_cache
 from repro.models import model as model_lib
 from repro.optim import (AdamWConfig, accumulate_gradients, adamw_init,
                          adamw_update, compressed_gradients)
@@ -72,7 +73,30 @@ def main(argv=None):
     if args.reduced:
         cfg = cfg.reduced()
     cfg = dataclasses.replace(cfg, remat="none" if args.reduced else "full")
+    history = train(
+        cfg, steps=args.steps, global_batch=args.global_batch,
+        seq_len=args.seq_len, microbatches=args.microbatches, lr=args.lr,
+        warmup=args.warmup, checkpoint_dir=args.checkpoint_dir,
+        checkpoint_every=args.checkpoint_every,
+        compress_grads=args.compress_grads, seed=args.seed,
+        log_every=args.log_every)
+    return history[-1].loss if history else float("nan")
 
+
+@dataclasses.dataclass(frozen=True)
+class StepLog:
+    step: int
+    loss: float
+    grad_norm: float
+    seconds: float          # host clock around the step, to its loss
+
+
+def train(cfg, *, steps: int, global_batch: int, seq_len: int,
+          microbatches: int = 1, lr: float = 3e-4, warmup: int = 20,
+          checkpoint_dir: str | None = None, checkpoint_every: int = 50,
+          compress_grads: bool = False, seed: int = 0,
+          log_every: int = 10) -> list[StepLog]:
+    """Run the training loop for ``cfg``; one :class:`StepLog` per step."""
     # paper technique: steal table from the (modeled) topology
     steal = None
     if cfg.moe_num_experts:
@@ -82,59 +106,63 @@ def main(argv=None):
         owners = np.arange(cfg.moe_num_experts) % topo.num_cores
         steal = expert_steal_table(topo, owners, cfg.moe_steal_policy)
 
-    key = jax.random.PRNGKey(args.seed)
+    key = jax.random.PRNGKey(seed)
     params = model_lib.init_params(cfg, key)
-    opt_cfg = AdamWConfig(lr_peak=args.lr, warmup_steps=args.warmup,
-                          total_steps=args.steps)
+    opt_cfg = AdamWConfig(lr_peak=lr, warmup_steps=warmup,
+                          total_steps=steps)
     opt_state = adamw_init(params, opt_cfg)
 
     pipe = TokenPipeline(PipelineConfig(
-        vocab_size=cfg.vocab_size, seq_len=args.seq_len,
-        global_batch=args.global_batch, seed=args.seed,
+        vocab_size=cfg.vocab_size, seq_len=seq_len,
+        global_batch=global_batch, seed=seed,
         embeds_dim=cfg.d_model if cfg.embeds_input else 0,
         media_tokens=cfg.num_media_tokens, d_model=cfg.d_model))
 
     start_step = 0
     mgr = None
-    if args.checkpoint_dir:
-        mgr = CheckpointManager(args.checkpoint_dir, keep_last=3)
+    if checkpoint_dir:
+        mgr = CheckpointManager(checkpoint_dir, keep_last=3)
         got = mgr.restore_latest({"params": params, "opt": opt_state})
         if got[0] is not None:
             start_step, tree = got
             params, opt_state = tree["params"], tree["opt"]
             print(f"[train] resumed from step {start_step}")
 
-    step_fn = jax.jit(build_train_step(cfg, opt_cfg, args.microbatches,
-                                       steal, args.compress_grads))
+    step_fn = jax.jit(build_train_step(cfg, opt_cfg, microbatches,
+                                       steal, compress_grads))
     comp_state = None
     monitor = HeartbeatMonitor(num_hosts=1)
     it = Prefetcher(pipe.iter_from(start_step))
 
     t_start = time.time()
     tokens_done = 0
-    loss = float("nan")
-    for step in range(start_step, args.steps):
+    history: list[StepLog] = []
+    for step in range(start_step, steps):
         batch = next(it)
         t0 = time.time()
         params, opt_state, comp_state, loss, gnorm = step_fn(
             params, opt_state, comp_state, batch)
-        loss = jax.block_until_ready(loss)
+        loss = float(jax.block_until_ready(loss))
         dt = time.time() - t0
+        gnorm = float(gnorm)
+        history.append(StepLog(step, loss, gnorm, dt))
         monitor.beat(0, dt)
-        tokens_done += args.global_batch * args.seq_len
-        if step % args.log_every == 0 or step == args.steps - 1:
-            print(f"[train] step {step:5d} loss {float(loss):8.4f} "
-                  f"gnorm {float(gnorm):7.3f} {dt*1e3:7.1f} ms/step "
+        tokens_done += global_batch * seq_len
+        if step % log_every == 0 or step == steps - 1:
+            print(f"[train] step {step:5d} loss {loss:8.4f} "
+                  f"gnorm {gnorm:7.3f} {dt*1e3:7.1f} ms/step "
                   f"{tokens_done/(time.time()-t_start):9.0f} tok/s")
-        if mgr and (step + 1) % args.checkpoint_every == 0:
+        if mgr and (step + 1) % checkpoint_every == 0:
             mgr.save_async(step + 1, {"params": params, "opt": opt_state})
     if mgr:
-        mgr.save_sync(args.steps, {"params": params, "opt": opt_state})
+        mgr.save_sync(steps, {"params": params, "opt": opt_state})
         mgr.wait()
     it.close()
-    print(f"[train] done: final loss {float(loss):.4f}")
-    return float(loss)
+    if history:
+        print(f"[train] done: final loss {history[-1].loss:.4f}")
+    return history
 
 
 if __name__ == "__main__":
+    use_persistent_compile_cache()
     main()

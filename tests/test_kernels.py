@@ -178,3 +178,29 @@ def test_moe_gmm_grads():
     g1 = jax.grad(lambda w: ops.moe_gmm(x, w).sum())(w)
     g2 = jax.grad(lambda w: ref.moe_gmm_ref(x, w).sum())(w)
     np.testing.assert_allclose(g1, g2, rtol=1e-4, atol=1e-5)
+
+
+# ----------------------------------------------------------------------
+# untiled shapes: oracle off the chip, ValueError on a TPU
+# ----------------------------------------------------------------------
+
+_UNTILED = {
+    "flash_attention": lambda: ops.flash_attention(
+        *[_rand((1, 100, 2, 16), jnp.float32, KEY)] * 3, block_q=64),
+    "ssd_scan": lambda: ops.ssd_scan(
+        _rand((1, 100, 2, 16), jnp.float32, KEY, 0.5),
+        -jnp.abs(_rand((1, 100, 2), jnp.float32, KEY, 0.3)),
+        _rand((1, 100, 1, 8), jnp.float32, KEY, 0.3),
+        _rand((1, 100, 1, 8), jnp.float32, KEY, 0.3), chunk=64),
+    "moe_gmm": lambda: ops.moe_gmm(
+        _rand((2, 100, 48), jnp.float32, KEY),
+        _rand((2, 48, 72), jnp.float32, KEY), block_c=64),
+}
+
+
+@pytest.mark.parametrize("op", sorted(_UNTILED))
+def test_untiled_shape_raises_on_tpu(monkeypatch, op):
+    _UNTILED[op]()                                   # CPU: the oracle runs
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match=op):
+        _UNTILED[op]()

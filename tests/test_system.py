@@ -5,6 +5,7 @@ plumbing (single-device)."""
 import dataclasses
 import os
 import tempfile
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -73,11 +74,14 @@ def test_checkpoint_restart_bitwise_resume():
 
 
 def test_serving_driver_runs():
-    gen = serve_mod.main(["--arch", "qwen2.5-3b", "--reduced",
+    res = serve_mod.main(["--arch", "qwen2.5-3b", "--reduced",
                           "--batch", "2", "--prompt-len", "16",
                           "--gen", "4"])
+    gen = res.tokens
     assert gen.shape == (2, 4)
     assert (gen >= 0).all() and (gen < 256).all()
+    assert res.prefill_logits.shape == (2, 1, 256)
+    assert np.isfinite(res.prefill_logits).all()
 
 
 @pytest.mark.slow
@@ -141,3 +145,28 @@ def test_skipped_cells_documented():
         assert run | skip == set(configs.SHAPES)
         total += len(run)
     assert total == 31      # 40 cells − 9 documented skips
+
+
+# ----------------------------------------------------------------------
+# persistent compilation cache placement
+# ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_placement(monkeypatch, tmp_path, env_dir):
+    from repro.launch import jax_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        repo = Path(configs.__file__).resolve().parents[3]
+        assert jax_cache.REPO_CACHE_DIR == repo / ".jax_cache"
+        want = str(jax_cache.REPO_CACHE_DIR)
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    try:
+        assert jax_cache.use_persistent_compile_cache() == want
+        # with the variable set, the location is JAX's to read
+        assert jax.config.jax_compilation_cache_dir == \
+            (before if env_dir else want)
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
