@@ -119,6 +119,7 @@ def flash_attention_kernel_call(q: jnp.ndarray, k: jnp.ndarray,
         kv_offset=kv_offset, block_q=block_q, block_k=block_k)
     return pl.pallas_call(
         kernel,
+        name="flash_attention",
         out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
         grid=grid,
         in_specs=[

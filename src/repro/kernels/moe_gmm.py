@@ -57,6 +57,7 @@ def moe_gmm_kernel_call(x: jnp.ndarray, w: jnp.ndarray,
     grid = (E, C // block_c, F // block_f, D // block_d)
     return pl.pallas_call(
         _kernel,
+        name="moe_gmm",
         out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
         grid=grid,
         in_specs=[

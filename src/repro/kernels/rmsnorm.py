@@ -35,6 +35,7 @@ def rmsnorm_kernel_call(x: jnp.ndarray, w: jnp.ndarray,
         raise ValueError(f"rows {n} not divisible by block_rows {block_rows}")
     return pl.pallas_call(
         functools.partial(_kernel, eps=eps),
+        name="rmsnorm",
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
         grid=(n // block_rows,),
         in_specs=[pl.BlockSpec((block_rows, d), lambda i: (i, 0)),

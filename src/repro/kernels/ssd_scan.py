@@ -104,6 +104,7 @@ def ssd_scan_kernel_call(x: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
     grid = (B, H, S // chunk)
     y, hT = pl.pallas_call(
         functools.partial(_kernel, chunk=chunk),
+        name="ssd_scan",
         out_shape=(jax.ShapeDtypeStruct((B, H, S, P), x.dtype),
                    jax.ShapeDtypeStruct((B, H, N, P), jnp.float32)),
         grid=grid,

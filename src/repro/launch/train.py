@@ -1,10 +1,9 @@
 """End-to-end training driver.
 
 Production-shaped loop: grad accumulation, checkpoint-every-k with async
-writes + exact resume (stateless data pipeline), straggler monitoring
-hooks, optional int8-compressed cross-pod gradients, and the paper's MoE
-steal tables. The step is jitted for one device; the mesh path is not
-wired in yet.
+writes + exact resume (stateless data pipeline), optional int8-compressed
+cross-pod gradients, and the paper's MoE steal tables. The step is jitted
+for one device; the mesh path is not wired in yet.
 
 ``main`` parses arguments and builds the config; :func:`train` runs the
 loop for any ``ArchConfig`` (``chip_smoke.py`` passes a depth-cut one).
@@ -33,7 +32,6 @@ from repro.launch.jax_cache import use_persistent_compile_cache
 from repro.models import model as model_lib
 from repro.optim import (AdamWConfig, accumulate_gradients, adamw_init,
                          adamw_update, compressed_gradients)
-from repro.runtime import HeartbeatMonitor
 
 
 def build_train_step(cfg, opt_cfg, n_micro, steal_table, compress=False):
@@ -131,7 +129,6 @@ def train(cfg, *, steps: int, global_batch: int, seq_len: int,
     step_fn = jax.jit(build_train_step(cfg, opt_cfg, microbatches,
                                        steal, compress_grads))
     comp_state = None
-    monitor = HeartbeatMonitor(num_hosts=1)
     it = Prefetcher(pipe.iter_from(start_step))
 
     t_start = time.time()
@@ -146,7 +143,6 @@ def train(cfg, *, steps: int, global_batch: int, seq_len: int,
         dt = time.time() - t0
         gnorm = float(gnorm)
         history.append(StepLog(step, loss, gnorm, dt))
-        monitor.beat(0, dt)
         tokens_done += global_batch * seq_len
         if step % log_every == 0 or step == steps - 1:
             print(f"[train] step {step:5d} loss {loss:8.4f} "

@@ -103,6 +103,7 @@ def init_attention(key, cfg) -> Params:
     return p
 
 
+@jax.named_scope("attention")
 def attention(x, p, cfg, *, positions, cache=None, causal=True):
     """Self attention. cache: None | dict(k, v, length: scalar int32).
 
@@ -184,6 +185,7 @@ def init_cross_attention(key, cfg) -> Params:
     }
 
 
+@jax.named_scope("attention")
 def cross_attention(x, p, cfg, *, media, cache=None):
     """Cross attention onto media embeddings (B, M, D_model).
 
@@ -228,8 +230,13 @@ def init_mlp(key, cfg, d_ff=None) -> Params:
     }
 
 
-def mlp(x, p):
+def _swiglu(x, p):
     return (jax.nn.silu(x @ p["wg"]) * (x @ p["wu"])) @ p["wd"]
+
+
+@jax.named_scope("mlp")
+def mlp(x, p):
+    return _swiglu(x, p)
 
 
 def init_moe(key, cfg) -> Params:
@@ -284,36 +291,41 @@ def moe(x, p, cfg, steal_table=None):
         return r["expert"], r["slot"], r["weight"], r["aux_loss"]
 
     # routing per group (small tensors) …
-    expert, slot, weight, aux = jax.vmap(route_group)(xg)
+    with jax.named_scope("moe.route"):
+        expert, slot, weight, aux = jax.vmap(route_group)(xg)
     # … but the heavy dispatch/expert einsums keep the group dim explicit
     # so the sharding constraints apply at the jit level (groups ride the
     # DP axes, experts the model axis — constraints under vmap are not
     # reliably honored by GSPMD).
-    e_oh = jax.nn.one_hot(expert, E, dtype=xg.dtype)       # (g,s,K,E)
-    c_oh = jax.nn.one_hot(slot, capacity, dtype=xg.dtype)  # (g,s,K,C)
-    combine = jnp.einsum("gske,gskc,gsk->gsec", e_oh, c_oh,
-                         weight.astype(xg.dtype))
-    dispatch = jnp.einsum("gske,gskc->gsec", e_oh, c_oh)
-    xin = jnp.einsum("gsec,gsd->gecd", dispatch, xg)       # (g,E,C,D)
-    xin = _constrain(xin, cfg.moe_xin_spec)
-    if cfg.moe_impl == "kernel":
-        flat = xin.reshape(ngroups * E, capacity, D)
-        wg_f = jnp.tile(p["wg"], (ngroups, 1, 1))
-        wu_f = jnp.tile(p["wu"], (ngroups, 1, 1))
-        wd_f = jnp.tile(p["wd"], (ngroups, 1, 1))
-        h = jax.nn.silu(kops.moe_gmm(flat, wg_f)) * kops.moe_gmm(flat, wu_f)
-        eout = kops.moe_gmm(h, wd_f).reshape(ngroups, E, capacity, D)
-    else:
-        h = jnp.einsum("gecd,edf->gecf", xin, p["wg"])
-        u = jnp.einsum("gecd,edf->gecf", xin, p["wu"])
-        h = jax.nn.silu(h) * u
-        h = _constrain(h, cfg.moe_h_spec)
-        eout = jnp.einsum("gecf,efd->gecd", h, p["wd"])
-    eout = _constrain(eout, cfg.moe_xin_spec)
-    y = jnp.einsum("gsec,gecd->gsd", combine, eout)
-    y = y.reshape(B, S, D)
-    if cfg.moe_shared_expert:
-        y = y + mlp(x, p["shared"])
+    with jax.named_scope("moe.dispatch"):
+        e_oh = jax.nn.one_hot(expert, E, dtype=xg.dtype)       # (g,s,K,E)
+        c_oh = jax.nn.one_hot(slot, capacity, dtype=xg.dtype)  # (g,s,K,C)
+        combine = jnp.einsum("gske,gskc,gsk->gsec", e_oh, c_oh,
+                             weight.astype(xg.dtype))
+        dispatch = jnp.einsum("gske,gskc->gsec", e_oh, c_oh)
+        xin = jnp.einsum("gsec,gsd->gecd", dispatch, xg)       # (g,E,C,D)
+        xin = _constrain(xin, cfg.moe_xin_spec)
+    with jax.named_scope("moe.experts"):
+        if cfg.moe_impl == "kernel":
+            flat = xin.reshape(ngroups * E, capacity, D)
+            wg_f = jnp.tile(p["wg"], (ngroups, 1, 1))
+            wu_f = jnp.tile(p["wu"], (ngroups, 1, 1))
+            wd_f = jnp.tile(p["wd"], (ngroups, 1, 1))
+            h = (jax.nn.silu(kops.moe_gmm(flat, wg_f))
+                 * kops.moe_gmm(flat, wu_f))
+            eout = kops.moe_gmm(h, wd_f).reshape(ngroups, E, capacity, D)
+        else:
+            h = jnp.einsum("gecd,edf->gecf", xin, p["wg"])
+            u = jnp.einsum("gecd,edf->gecf", xin, p["wu"])
+            h = jax.nn.silu(h) * u
+            h = _constrain(h, cfg.moe_h_spec)
+            eout = jnp.einsum("gecf,efd->gecd", h, p["wd"])
+        eout = _constrain(eout, cfg.moe_xin_spec)
+    with jax.named_scope("moe.combine"):
+        y = jnp.einsum("gsec,gecd->gsd", combine, eout)
+        y = y.reshape(B, S, D)
+        if cfg.moe_shared_expert:
+            y = y + _swiglu(x, p["shared"])
     return y, jnp.mean(aux)
 
 
@@ -374,42 +386,50 @@ def mamba(x, p, cfg, cache=None):
     B, S, D = x.shape
     d_inner, G, N, H = _mamba_split(cfg)
     P = cfg.ssm_head_dim
-    proj = x @ p["in_proj"]
-    z, xbc, dtp = jnp.split(
-        proj, [d_inner, 2 * d_inner + 2 * G * N], axis=-1)
-    conv_state = cache["conv"] if cache is not None else None
-    xbc, new_conv = _causal_conv(xbc, p["conv_w"], p["conv_b"], conv_state)
-    xs, bmat, cmat = jnp.split(xbc, [d_inner, d_inner + G * N], axis=-1)
-    xs = xs.reshape(B, S, H, P)
-    bmat = bmat.reshape(B, S, G, N)
-    cmat = cmat.reshape(B, S, G, N)
-    dt = jax.nn.softplus(dtp.astype(jnp.float32) + p["dt_bias"])  # (B,S,H)
-    a = -jnp.exp(p["A_log"])[None, None, :] * dt                  # (B,S,H)
-    x_dt = xs * dt[..., None].astype(xs.dtype)
-    x_dt = _constrain(x_dt, cfg.ssm_act_spec)
+    with jax.named_scope("mamba.in_proj"):
+        proj = x @ p["in_proj"]
+        z, xbc, dtp = jnp.split(
+            proj, [d_inner, 2 * d_inner + 2 * G * N], axis=-1)
+    with jax.named_scope("mamba.conv"):
+        conv_state = cache["conv"] if cache is not None else None
+        xbc, new_conv = _causal_conv(xbc, p["conv_w"], p["conv_b"],
+                                     conv_state)
+    with jax.named_scope("mamba.ssd"):
+        xs, bmat, cmat = jnp.split(xbc, [d_inner, d_inner + G * N], axis=-1)
+        xs = xs.reshape(B, S, H, P)
+        bmat = bmat.reshape(B, S, G, N)
+        cmat = cmat.reshape(B, S, G, N)
+        dt = jax.nn.softplus(dtp.astype(jnp.float32) + p["dt_bias"])
+        a = -jnp.exp(p["A_log"])[None, None, :] * dt              # (B,S,H)
+        x_dt = xs * dt[..., None].astype(xs.dtype)
+        x_dt = _constrain(x_dt, cfg.ssm_act_spec)
 
-    if cache is None:
-        if cfg.ssm_impl == "kernel":
-            y, _ = kops.ssd_scan(x_dt, a, bmat, cmat, chunk=cfg.ssm_chunk)
-        else:
-            y = kref.ssd_chunked_ref(x_dt, a, bmat, cmat,
+        if cache is None:
+            if cfg.ssm_impl == "kernel":
+                y, _ = kops.ssd_scan(x_dt, a, bmat, cmat,
                                      chunk=cfg.ssm_chunk)
-        new_cache = None
-    elif S > 1:
-        # chunked prefill with carried state
-        h0 = cache["ssm"]                                 # (B,H,N,P) f32
-        y, hT = kref.ssd_chunked_ref(x_dt, a, bmat, cmat, h0=h0,
-                                     chunk=cfg.ssm_chunk,
-                                     return_state=True)
-        new_cache = dict(conv=new_conv, ssm=hT)
-    else:
-        h0 = cache["ssm"]
-        y, hT = kref.ssd_ref(x_dt, a, bmat, cmat, h0=h0, return_state=True)
-        new_cache = dict(conv=new_conv, ssm=hT)
-    y = y + xs * p["D_skip"][None, None, :, None].astype(xs.dtype)
-    y = y.reshape(B, S, d_inner)
-    y = rmsnorm(y * jax.nn.silu(z), p["out_norm"], cfg.norm_eps)
-    return y @ p["out_proj"], new_cache
+            else:
+                y = kref.ssd_chunked_ref(x_dt, a, bmat, cmat,
+                                         chunk=cfg.ssm_chunk)
+            new_cache = None
+        elif S > 1:
+            # chunked prefill with carried state
+            h0 = cache["ssm"]                             # (B,H,N,P) f32
+            y, hT = kref.ssd_chunked_ref(x_dt, a, bmat, cmat, h0=h0,
+                                         chunk=cfg.ssm_chunk,
+                                         return_state=True)
+            new_cache = dict(conv=new_conv, ssm=hT)
+        else:
+            h0 = cache["ssm"]
+            y, hT = kref.ssd_ref(x_dt, a, bmat, cmat, h0=h0,
+                                 return_state=True)
+            new_cache = dict(conv=new_conv, ssm=hT)
+        y = y + xs * p["D_skip"][None, None, :, None].astype(xs.dtype)
+    with jax.named_scope("mamba.out"):
+        y = y.reshape(B, S, d_inner)
+        y = rmsnorm(y * jax.nn.silu(z), p["out_norm"], cfg.norm_eps)
+        y = y @ p["out_proj"]
+    return y, new_cache
 
 
 def mamba_cache_init(cfg, batch, dtype):

@@ -48,6 +48,7 @@ def abstract_params(cfg, dtype_override=None):
     return out
 
 
+@jax.named_scope("embed")
 def _embed(params, cfg, tokens=None, embeds=None):
     if cfg.embeds_input:
         assert embeds is not None, "this arch takes frontend embeddings"
@@ -55,6 +56,7 @@ def _embed(params, cfg, tokens=None, embeds=None):
     return params["embed"][tokens]
 
 
+@jax.named_scope("head")
 def _head(params, cfg, x):
     x = layers.rmsnorm(x, params["final_norm"], cfg.norm_eps)
     if cfg.tie_embeddings and "lm_head" not in params:
@@ -85,17 +87,18 @@ def train_loss(params, cfg, batch, steal_table=None):
                           embeds=batch.get("embeds"),
                           media=batch.get("media"),
                           steal_table=steal_table)
-    labels = batch["labels"]
-    valid = labels >= 0
-    labels_safe = jnp.where(valid, labels, 0)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, labels_safe[..., None], -1)[..., 0]
-    denom = jnp.maximum(valid.sum(), 1)
-    ce = -(ll * valid).sum() / denom
-    # z-loss stabilizes the softmax normalizer at scale
-    zl = jnp.square(jax.nn.logsumexp(logits, axis=-1))
-    z_loss = (zl * valid).sum() / denom
-    loss = ce + cfg.router_aux_weight * aux + cfg.z_loss_weight * z_loss
+    with jax.named_scope("head"):
+        labels = batch["labels"]
+        valid = labels >= 0
+        labels_safe = jnp.where(valid, labels, 0)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, labels_safe[..., None], -1)[..., 0]
+        denom = jnp.maximum(valid.sum(), 1)
+        ce = -(ll * valid).sum() / denom
+        # z-loss stabilizes the softmax normalizer at scale
+        zl = jnp.square(jax.nn.logsumexp(logits, axis=-1))
+        z_loss = (zl * valid).sum() / denom
+        loss = ce + cfg.router_aux_weight * aux + cfg.z_loss_weight * z_loss
     return loss, dict(ce=ce, aux=aux, z_loss=z_loss)
 
 

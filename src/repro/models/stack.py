@@ -94,7 +94,8 @@ def apply_stack(params, cfg, x, *, positions, media=None, caches=None,
 
     def make_slot_fn(si, kind, ffn):
         def slot_fn(h, p, c):
-            hin = layers.rmsnorm(h, p["ln1"], cfg.norm_eps)
+            with jax.named_scope("norm"):
+                hin = layers.rmsnorm(h, p["ln1"], cfg.norm_eps)
             if kind == "attn":
                 cc = dict(c, length=length) if c is not None else None
                 y, nc = layers.attention(hin, p["mix"], cfg,
@@ -116,7 +117,8 @@ def apply_stack(params, cfg, x, *, positions, media=None, caches=None,
             h = h + y
             aux = jnp.zeros((), jnp.float32)
             if ffn != "none":
-                hin = layers.rmsnorm(h, p["ln2"], cfg.norm_eps)
+                with jax.named_scope("norm"):
+                    hin = layers.rmsnorm(h, p["ln2"], cfg.norm_eps)
                 if ffn == "moe":
                     y, aux = layers.moe(hin, p["ffn"], cfg, steal_table)
                 else:

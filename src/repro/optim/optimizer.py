@@ -80,6 +80,7 @@ def clip_by_global_norm(grads: Params, max_norm: float):
                         .astype(x.dtype), grads), g
 
 
+@jax.named_scope("optimizer")
 def adamw_update(grads: Params, state: dict, params: Params,
                  cfg: AdamWConfig):
     """One AdamW step. Returns (new_params, new_state, metrics)."""
