@@ -142,48 +142,78 @@ def ssd_chunked_ref(x: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
                     chunk: int = 128,
                     return_state: bool = False):
     """Chunked (dual-form) SSD — same semantics as :func:`ssd_ref`, but
-    MXU-shaped: dense intra-chunk matmuls + a scan over S/chunk chunk
-    states. This is the pure-jnp mirror of the Pallas kernel's math and
-    the training/prefill path of the Mamba2 layers (the sequential scan
-    would put S serialized steps in the HLO)."""
+    MXU-shaped, with every chunk computed at once (the chunk-parallel form
+    of arXiv:2405.21060 §6). This is the training/prefill path of the
+    Mamba2 layers (the sequential scan would put S serialized steps in the
+    HLO). With L = chunk, nc = S / L and R = H / G heads a group, each group
+    joins the batch axis (B·G), so B and C are never repeated to the heads:
+
+      diagonal blocks  C·Bᵀ once per group (B·G, nc, L, L), times the
+                       masked exp(segsum) decay of each head, laid out
+                       heads-major (B·G, nc, R, L, L), contracted with x;
+      chunk states     Bᵀ·(exp(a_tot − acum) ⊙ x), (B·G, nc, R, N, P);
+      inter-chunk      the nc + 1 states (h0 first) mixed by the masked
+                       exp(segsum) of the chunk totals: the state entering
+                       each chunk, and hT;
+      off-diagonal     exp(acum) ⊙ C·h_in.
+
+    Every tensor ``ssd_ref`` keeps in f32 stays f32; the inter-chunk mix
+    runs at full f32 matmul precision, as the recurrence it replaces. (A
+    separate group axis would have size 1 in the common G = 1 case, and
+    XLA's CPU compiler drops the named scope of a dot with a batch axis of
+    size 1.)"""
     B, S, H, P = x.shape
     _, _, G, N = b.shape
     if S % chunk or S == 0:
         return ssd_ref(x, a, b, c, h0=h0, return_state=return_state)
-    rep = H // G
-    L = chunk
-    nc = S // L
-    bb = jnp.repeat(b, rep, axis=2).astype(jnp.float32)
-    cc = jnp.repeat(c, rep, axis=2).astype(jnp.float32)
-    xf = x.astype(jnp.float32)
-    af = a.astype(jnp.float32)
-    if h0 is None:
-        h0 = jnp.zeros((B, H, N, P), jnp.float32)
+    if H % G:
+        raise ValueError(f"H={H} not a multiple of G={G}")
+    R, L, nc = H // G, chunk, S // chunk
+    f32 = jnp.float32
 
-    def chunk_f(h, inp):
-        xc, ac, bc, cx = inp               # (B,L,H,P) (B,L,H) (B,L,H,N) ×2
-        acum = jnp.cumsum(ac, axis=1)      # inclusive
-        a_tot = acum[:, -1]                # (B,H)
-        y_inter = jnp.exp(acum)[..., None] * jnp.einsum(
-            "blhn,bhnp->blhp", cx, h)
-        logdecay = acum[:, :, None, :] - acum[:, None, :, :]   # (B,L,L,H)
-        tri = (jnp.arange(L)[:, None] >= jnp.arange(L)[None, :])
-        # mask BEFORE exp: the upper triangle holds positive values whose
-        # exp overflows; inf·0 in the backward would produce NaN grads.
-        decay = jnp.exp(jnp.where(tri[None, :, :, None], logdecay, -jnp.inf))
-        scores = jnp.einsum("blhn,bmhn->blmh", cx, bc) * decay
-        y = y_inter + jnp.einsum("blmh,bmhp->blhp", scores, xc)
-        w = jnp.exp(a_tot[:, None] - acum)[..., None] * bc     # (B,L,H,N)
-        h = jnp.exp(a_tot)[..., None, None] * h + jnp.einsum(
-            "blhn,blhp->bhnp", w, xc)
-        return h, y
+    def by_group(t):
+        """(B, S, G·k…) -> (B·G, nc, L, k…): each group joins the batch."""
+        t = jnp.moveaxis(t.reshape(B, S, G, -1), 2, 1)
+        return t.reshape((B * G, nc, L) + t.shape[3:]).astype(f32)
 
-    resh = lambda t: jnp.moveaxis(
-        t.reshape((B, nc, L) + t.shape[2:]), 1, 0)
-    hT, ys = jax.lax.scan(
-        chunk_f, h0.astype(jnp.float32),
-        (resh(xf), resh(af), resh(bb), resh(cc)))
-    y = jnp.moveaxis(ys, 0, 1).reshape(B, S, H, P).astype(x.dtype)
+    xf = by_group(x.reshape(B, S, H * P)).reshape(B * G, nc, L, R, P)
+    bf, cf = by_group(b), by_group(c)          # (BG,nc,L,N)
+    acum = jnp.cumsum(jnp.moveaxis(by_group(a), 2, -1), axis=-1)
+    a_tot = acum[..., -1]                      # (BG,nc,R); acum (BG,nc,R,L)
+
+    def masked_exp_segsum(cum):
+        """exp(cum[i] − cum[j]) for i ≥ j, else 0, over the last axis.
+
+        Mask BEFORE exp: the upper triangle holds positive values whose
+        exp overflows; inf·0 in the backward would produce NaN grads."""
+        n = cum.shape[-1]
+        tri = jnp.arange(n)[:, None] >= jnp.arange(n)[None, :]
+        return jnp.exp(jnp.where(tri, cum[..., :, None] - cum[..., None, :],
+                                 -jnp.inf))
+
+    # diagonal blocks: C·Bᵀ per group, decay per head (BG,nc,R,L,L)
+    cb = jnp.einsum("bcln,bcmn->bclm", cf, bf)
+    scores = cb[:, :, None] * masked_exp_segsum(acum)
+    y = jnp.moveaxis(jnp.einsum("bcmrp,bcrlm->bcrpl", xf, scores), -1, 2)
+
+    # each chunk's own state, from zero
+    decay_in = jnp.moveaxis(jnp.exp(a_tot[..., None] - acum), -1, 2)
+    states = jnp.einsum("bcln,bclrp->bcrnp", bf, decay_in[..., None] * xf)
+
+    # inter-chunk: h_all[z] is the state entering chunk z; h_all[nc] is hT
+    h_init = (jnp.zeros((B, H, N, P), f32) if h0 is None
+              else h0.astype(f32)).reshape(B * G, 1, R, N, P)
+    states = jnp.concatenate([h_init, states], axis=1)
+    cum_tot = jnp.cumsum(jnp.pad(jnp.moveaxis(a_tot, 1, -1),
+                                 [(0, 0), (0, 0), (1, 0)]), axis=-1)
+    h_all = jnp.einsum("brzc,bcrnp->bzrnp", masked_exp_segsum(cum_tot),
+                       states, precision=jax.lax.Precision.HIGHEST)
+
+    # off-diagonal: what the entering state contributes inside each chunk
+    y = y + jnp.moveaxis(jnp.exp(acum), -1, 2)[..., None] * jnp.moveaxis(
+        jnp.einsum("bcrnp,bcln->bcrpl", h_all[:, :nc], cf), -1, 2)
+    y = jnp.moveaxis(y.reshape(B, G, S, R, P), 1, 2)
+    y = y.reshape(B, S, H, P).astype(x.dtype)
     if return_state:
-        return y, hT
+        return y, h_all[:, nc].reshape(B, H, N, P)
     return y

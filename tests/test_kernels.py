@@ -121,19 +121,63 @@ def test_ssd_kernel_vs_ref(S, H, P, G, N, chunk):
     np.testing.assert_allclose(h1, h2, rtol=2e-3, atol=2e-4)
 
 
-def test_ssd_chunked_ref_with_state():
-    """Chunked dual form == sequential scan, including carried state."""
+def _ssd_inputs(S, H, G, dtype=jnp.float32, batch=2, P=16, N=8):
     ks = jax.random.split(KEY, 5)
-    x = _rand((1, 128, 2, 16), jnp.float32, ks[0], 0.5)
-    a = -jnp.abs(_rand((1, 128, 2), jnp.float32, ks[1], 0.3))
-    b = _rand((1, 128, 1, 8), jnp.float32, ks[2], 0.3)
-    c = _rand((1, 128, 1, 8), jnp.float32, ks[3], 0.3)
-    h0 = _rand((1, 2, 8, 16), jnp.float32, ks[4], 0.2)
-    y1, h1 = ref.ssd_chunked_ref(x, a, b, c, h0=h0, chunk=32,
+    x = _rand((batch, S, H, P), dtype, ks[0], 0.5)
+    a = -jnp.abs(_rand((batch, S, H), jnp.float32, ks[1], 0.3))
+    b = _rand((batch, S, G, N), dtype, ks[2], 0.3)
+    c = _rand((batch, S, G, N), dtype, ks[3], 0.3)
+    h0 = _rand((batch, H, N, P), jnp.float32, ks[4], 0.2)
+    return x, a, b, c, h0
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("S,chunk", [(128, 32), (64, 64)])  # 4 chunks, 1
+@pytest.mark.parametrize("batch,H,G", [(2, 2, 1), (2, 4, 2), (1, 2, 1)])
+@pytest.mark.parametrize("with_h0", [True, False])
+def test_ssd_chunked_ref_with_state(with_h0, batch, H, G, S, chunk, dtype):
+    """Chunked dual form == sequential scan: outputs and final state."""
+    x, a, b, c, h0 = _ssd_inputs(S, H, G, dtype, batch)
+    h0 = h0 if with_h0 else None
+    y1, h1 = ref.ssd_chunked_ref(x, a, b, c, h0=h0, chunk=chunk,
                                  return_state=True)
     y2, h2 = ref.ssd_ref(x, a, b, c, h0=h0, return_state=True)
-    np.testing.assert_allclose(y1, y2, rtol=2e-3, atol=2e-4)
-    np.testing.assert_allclose(h1, h2, rtol=2e-3, atol=2e-4)
+    assert y1.dtype == x.dtype and h1.dtype == jnp.float32
+    tol = 2e-4 if dtype == jnp.float32 else 1e-2     # y rounded to bf16
+    np.testing.assert_allclose(np.asarray(y1, np.float32),
+                               np.asarray(y2, np.float32), rtol=tol, atol=tol)
+    np.testing.assert_allclose(h1, h2, rtol=2e-4, atol=2e-5)
+
+
+def _ssd_grads(fn, args, **kw):
+    def loss(x, a, b, c, h0):
+        y, hT = fn(x, a, b, c, h0=h0, return_state=True, **kw)
+        return (y * y).sum() + (hT * hT).sum()
+    return jax.grad(loss, argnums=range(5))(*args)
+
+
+def test_ssd_chunked_ref_grad_matches_ref():
+    """Backward of the chunked form == backward of the sequential scan,
+    for x, a, B, C and h0."""
+    args = _ssd_inputs(128, 4, 2)
+    got = _ssd_grads(ref.ssd_chunked_ref, args, chunk=32)
+    want = _ssd_grads(ref.ssd_ref, args)
+    for name, g1, g2 in zip(("x", "a", "b", "c", "h0"), got, want):
+        np.testing.assert_allclose(g1, g2, rtol=2e-4, atol=2e-5,
+                                   err_msg=name)
+
+
+def test_ssd_chunked_ref_grad_finite_under_strong_decay():
+    """a = -30 a step: exp of the unmasked upper triangle would overflow,
+    and inf·0 would turn the gradients to NaN."""
+    x, _, b, c, h0 = _ssd_inputs(128, 4, 2)
+    a = jnp.full((2, 128, 4), -30.0)
+    got = _ssd_grads(ref.ssd_chunked_ref, (x, a, b, c, h0), chunk=32)
+    want = _ssd_grads(ref.ssd_ref, (x, a, b, c, h0))
+    for name, g1, g2 in zip(("x", "a", "b", "c", "h0"), got, want):
+        assert np.isfinite(np.asarray(g1)).all(), name
+        np.testing.assert_allclose(g1, g2, rtol=2e-4, atol=2e-5,
+                                   err_msg=name)
 
 
 def test_ssd_decode_continuity():
