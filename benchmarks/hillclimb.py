@@ -60,7 +60,7 @@ def cell_granite():
     rows.append(_run(a, s, m, "bf16grads",
                      opt_overrides=dict(factored=True,
                                         m_dtype="bfloat16")))
-    # H2: smaller routing groups shrink dispatch one-hots (memory) at the
+    # H2: smaller routing groups shrink routing's (K·G, E) fill at the
     # cost of more, smaller expert matmuls
     rows.append(_run(a, s, m, "group1024",
                      cfg_overrides=dict(moe_group=1024)))

@@ -28,8 +28,7 @@ import numpy as np
 from .stealing import steal_order_matrix
 from .topology import Topology
 
-__all__ = ["RoutingConfig", "expert_steal_table", "route",
-           "dispatch_combine_weights"]
+__all__ = ["RoutingConfig", "expert_steal_table", "route", "slot_maps"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -149,17 +148,37 @@ def route(gate_logits: jnp.ndarray,
                 drop_fraction=1.0 - jnp.mean(keep.astype(jnp.float32)))
 
 
-def dispatch_combine_weights(routing: dict, num_experts: int, capacity: int):
-    """Dense GShard-style tensors from a routing result.
+def slot_maps(expert: jnp.ndarray, slot: jnp.ndarray, num_experts: int,
+              capacity: int):
+    """Index maps between the (token, k) pairs and the (expert, slot) slots
+    of routed groups: what the MoE layer moves rows by.
+
+    Args:
+      expert, slot: (..., T, K) int32 from :func:`route`, one routed group
+        per leading index; -1 for a drop.
 
     Returns:
-      dispatch: (T, E, C) bool — token t occupies slot c of expert e.
-      combine:  (T, E, C) f32  — dispatch · weight.
+      pair_of_slot: (..., E·C) int32 — the flat pair t·K + k that fills
+        slot e·C + c; T·K, one past the last pair, for an empty slot.
+      slot_of_pair: (..., T, K) int32 — e·C + c of each pair; E·C, one
+        past the last slot, for a drop.
+
+    ``route`` gives each kept pair its own slot, so the two maps are
+    inverse on the kept pairs.
     """
-    expert, slot, w = routing["expert"], routing["slot"], routing["weight"]
-    T, K = expert.shape
-    e_oh = jax.nn.one_hot(expert, num_experts, dtype=jnp.float32)  # (T,K,E)
-    c_oh = jax.nn.one_hot(slot, capacity, dtype=jnp.float32)       # (T,K,C)
-    combine = jnp.einsum("tke,tkc,tk->tec", e_oh, c_oh, w)
-    dispatch = jnp.einsum("tke,tkc->tec", e_oh, c_oh) > 0
-    return dispatch, combine
+    *lead, T, K = expert.shape
+    n_slots = num_experts * capacity
+    kept = expert >= 0
+    slot_of_pair = jnp.where(kept, expert * capacity + slot,
+                             n_slots).astype(jnp.int32)
+    # one scatter for all groups, each group's slots after the previous
+    # group's (a scatter batched by vmap loses its op_name in XLA's
+    # rewrite); a drop indexes past the last slot and writes nothing
+    n_groups = int(np.prod(lead))
+    group = jnp.arange(n_groups, dtype=jnp.int32).reshape(*lead, 1, 1)
+    at = jnp.where(kept, group * n_slots + slot_of_pair, n_groups * n_slots)
+    pair = jnp.broadcast_to(
+        jnp.arange(T * K, dtype=jnp.int32).reshape(T, K), expert.shape)
+    pair_of_slot = jnp.full((n_groups * n_slots,), T * K, jnp.int32).at[
+        at.reshape(-1)].set(pair.reshape(-1), mode="drop")
+    return pair_of_slot.reshape(*lead, n_slots), slot_of_pair

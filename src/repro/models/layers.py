@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.routing import RoutingConfig, route
+from repro.core.routing import RoutingConfig, route, slot_maps
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 
@@ -255,11 +255,93 @@ def init_moe(key, cfg) -> Params:
     return p
 
 
+# Rows move between the token layout (g, T, D) and the slot layout, held
+# expert-major as (E, g, C, D): the layout the batched expert GEMMs read
+# and write, so no copy sits between them and the gathers. Each gather
+# batches over the group axis g; an index past the end reads zeros.
+_FILL = jax.lax.GatherScatterMode.FILL_OR_DROP
+
+
+def _to_slots(x, pair):
+    """out[e, g, c] = x[g, pair[e, g, c]]: x (g, N, ...), pair (E, g, C)."""
+    dn = jax.lax.GatherDimensionNumbers(
+        offset_dims=tuple(range(3, x.ndim + 1)), collapsed_slice_dims=(1,),
+        start_index_map=(1,), operand_batching_dims=(0,),
+        start_indices_batching_dims=(1,))
+    return jax.lax.gather(x, pair[..., None], dn, (1, 1) + x.shape[2:],
+                          mode=_FILL, fill_value=0)
+
+
+def _to_pairs(y, slot):
+    """out[g, t, k] = y[e, g, c] for slot[g, t, k] = e·C + c: y (E, g, C,
+    ...), slot (g, T, K)."""
+    C = y.shape[2]
+    dn = jax.lax.GatherDimensionNumbers(
+        offset_dims=tuple(range(3, y.ndim)), collapsed_slice_dims=(0, 2),
+        start_index_map=(0, 2), operand_batching_dims=(1,),
+        start_indices_batching_dims=(0,))
+    idx = jnp.stack([slot // C, slot % C], axis=-1)
+    return jax.lax.gather(y, idx, dn, (1, 1, 1) + y.shape[3:], mode=_FILL,
+                          fill_value=0)
+
+
+@jax.custom_vjp
+def _dispatch(xg, pair, slot):
+    """The experts' input (E, g, C, D): each slot's token row of ``xg``
+    (g, T, D), zeros in an empty slot. ``pair`` (E, g, C) and ``slot``
+    (g, T, K) are ``routing.slot_maps``' maps, expert-major."""
+    return _to_slots(xg, pair // slot.shape[-1])
+
+
+def _dispatch_fwd(xg, pair, slot):
+    return _dispatch(xg, pair, slot), slot
+
+
+def _dispatch_bwd(slot, dxin):
+    # a slot holds at most one pair: a token's gradient gathers its slots
+    with jax.named_scope("moe.dispatch"):
+        rows = _to_pairs(dxin, slot)                          # (g,T,K,D)
+        dxg = rows.astype(jnp.float32).sum(2).astype(dxin.dtype)
+    return dxg, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(eout, weight, pair, slot):
+    """y[g, t] = Σ_k weight[g, t, k] · eout[e, g, c], slot[g, t, k] =
+    e·C + c, in f32. eout: (E, g, C, D); weight: (g, T, K) f32, 0 for a
+    drop."""
+    rows = _to_pairs(eout, slot).astype(jnp.float32)          # (g,T,K,D)
+    return (rows * weight[..., None]).sum(2).astype(eout.dtype)
+
+
+def _combine_fwd(eout, weight, pair, slot):
+    return _combine(eout, weight, pair, slot), (eout, weight, pair, slot)
+
+
+def _combine_bwd(res, dy):
+    eout, weight, pair, slot = res
+    g, T, K = weight.shape
+    with jax.named_scope("moe.combine"):
+        w_slot = _to_slots(weight.reshape(g, T * K), pair)    # (E,g,C)
+        dy_slot = _to_slots(dy, pair // K).astype(jnp.float32)
+        deout = (dy_slot * w_slot[..., None]).astype(eout.dtype)
+        # the router's gradient: ⟨dy, eout⟩ per slot, read back per pair
+        dw_slot = (dy_slot * eout.astype(jnp.float32)).sum(-1)
+        dweight = _to_pairs(dw_slot, slot)
+    return deout, dweight, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
 def moe(x, p, cfg, steal_table=None):
     """Mixture of experts over (B, S, D) with locality-aware overflow.
 
     Tokens are routed in groups of ``cfg.moe_group`` (GShard-style) so the
-    dispatch tensors stay bounded; the router's overflow re-routing walks
+    expert slots stay bounded; the router's overflow re-routing walks
     the topology steal table (the paper's scheduler, see core/routing.py).
     Returns (y, aux_loss).
     """
@@ -293,18 +375,15 @@ def moe(x, p, cfg, steal_table=None):
     # routing per group (small tensors) …
     with jax.named_scope("moe.route"):
         expert, slot, weight, aux = jax.vmap(route_group)(xg)
-    # … but the heavy dispatch/expert einsums keep the group dim explicit
-    # so the sharding constraints apply at the jit level (groups ride the
-    # DP axes, experts the model axis — constraints under vmap are not
-    # reliably honored by GSPMD).
+    # … but rows move between tokens and expert slots by index gathers
+    # that keep the group dim explicit, so the sharding constraints apply
+    # at the jit level (groups ride the DP axes, experts the model axis —
+    # constraints under vmap are not reliably honored by GSPMD).
     with jax.named_scope("moe.dispatch"):
-        e_oh = jax.nn.one_hot(expert, E, dtype=xg.dtype)       # (g,s,K,E)
-        c_oh = jax.nn.one_hot(slot, capacity, dtype=xg.dtype)  # (g,s,K,C)
-        combine = jnp.einsum("gske,gskc,gsk->gsec", e_oh, c_oh,
-                             weight.astype(xg.dtype))
-        dispatch = jnp.einsum("gske,gskc->gsec", e_oh, c_oh)
-        xin = jnp.einsum("gsec,gsd->gecd", dispatch, xg)       # (g,E,C,D)
-        xin = _constrain(xin, cfg.moe_xin_spec)
+        pair_of_slot, slot_of_pair = slot_maps(expert, slot, E, capacity)
+        pair = pair_of_slot.reshape(ngroups, E, capacity).swapaxes(0, 1)
+        xin = _dispatch(xg, pair, slot_of_pair).swapaxes(0, 1)
+        xin = _constrain(xin, cfg.moe_xin_spec)                # (g,E,C,D)
     with jax.named_scope("moe.experts"):
         if cfg.moe_impl == "kernel":
             flat = xin.reshape(ngroups * E, capacity, D)
@@ -322,8 +401,8 @@ def moe(x, p, cfg, steal_table=None):
             eout = jnp.einsum("gecf,efd->gecd", h, p["wd"])
         eout = _constrain(eout, cfg.moe_xin_spec)
     with jax.named_scope("moe.combine"):
-        y = jnp.einsum("gsec,gecd->gsd", combine, eout)
-        y = y.reshape(B, S, D)
+        y = _combine(eout.swapaxes(0, 1), weight, pair,
+                     slot_of_pair).reshape(B, S, D)
         if cfg.moe_shared_expert:
             y = y + _swiglu(x, p["shared"])
     return y, jnp.mean(aux)

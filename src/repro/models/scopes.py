@@ -20,10 +20,11 @@ Scope               Wraps
 ``attention``       ``layers.attention`` and ``layers.cross_attention``
 ``mlp``             ``layers.mlp``
 ``moe.route``       the router matmul and ``routing.route``
-``moe.dispatch``    the one-hots, the dispatch and combine-weight einsums
-                    and the einsum to the experts' input
+``moe.dispatch``    the slot maps (``routing.slot_maps``) and the gather
+                    of the experts' input, with its gather backward
 ``moe.experts``     the expert GEMMs (einsum or the ``moe_gmm`` kernel)
-``moe.combine``     the einsum back to the tokens and the shared expert
+``moe.combine``     the weighted gather back to the tokens, its gather
+                    backward, and the shared expert
 ``head``            the final norm, the head matmul and the loss
 ``optimizer``       ``optim.adamw_update``, clipping included
 ==================  ====================================================

@@ -8,7 +8,7 @@ from _hypothesis_compat import given, settings, strategies as st
 
 from repro.core import topology
 from repro.core.routing import (RoutingConfig, expert_steal_table, route,
-                                dispatch_combine_weights)
+                                slot_maps)
 
 TOPO = topology.tpu_pod_2d(4, 4)
 TABLE = expert_steal_table(TOPO, np.arange(16), "dfwspt")
@@ -90,15 +90,53 @@ def test_stolen_tokens_go_to_nearest_free():
     assert (e == 0).sum() == 8     # expert 0 exactly at capacity
 
 
-def test_dispatch_combine_consistency():
-    cfg = RoutingConfig(8, top_k=2, capacity=16, steal_attempts=1)
+def _maps(cap=8, attempts=2, seed=3):
+    """A skewed routing of 64 tokens to 8 experts, top 2, and its maps."""
+    cfg = RoutingConfig(8, top_k=2, capacity=cap, steal_attempts=attempts)
     tbl = expert_steal_table(TOPO, np.arange(8) * 2, "dfwspt")
-    r = route(_logits(t=64, e=8, seed=3), cfg, tbl)
-    d, c = dispatch_combine_weights(r, 8, 16)
-    # each (expert, slot) column holds at most one token
-    assert (np.asarray(d).sum(axis=0) <= 1).all()
-    # combine weights sit exactly where dispatch is true
-    assert ((np.asarray(c) > 0) <= np.asarray(d)).all()
+    r = route(_logits(t=64, e=8, skew=[0, 1], seed=seed), cfg, tbl)
+    maps = slot_maps(r["expert"], r["slot"], 8, cap)
+    return (np.asarray(r["expert"]), np.asarray(r["slot"]),
+            *(np.asarray(m) for m in maps))
+
+
+@pytest.mark.parametrize("cap,attempts", [(8, 0), (8, 2), (32, 1)])
+def test_slot_maps_are_inverse_on_kept_pairs(cap, attempts):
+    expert, slot, pair_of_slot, slot_of_pair = _maps(cap, attempts)
+    kept = expert >= 0
+    assert (slot_of_pair[kept] == (expert * cap + slot)[kept]).all()
+    flat = slot_of_pair.reshape(-1)
+    filled = pair_of_slot < flat.size
+    # each filled slot holds one pair, and that pair names the slot back
+    assert (flat[pair_of_slot[filled]] == np.flatnonzero(filled)).all()
+    # each kept pair is the pair its slot holds
+    pairs = np.flatnonzero(kept.reshape(-1))
+    assert (pair_of_slot[flat[pairs]] == pairs).all()
+    assert filled.sum() == kept.sum()
+
+
+@pytest.mark.parametrize("cap,attempts", [(8, 0), (8, 2)])
+def test_drops_and_empty_slots_map_to_the_sentinel(cap, attempts):
+    expert, slot, pair_of_slot, slot_of_pair = _maps(cap, attempts)
+    n_pairs, n_slots = expert.size, 8 * cap
+    assert (expert < 0).any(), "the skew should overflow capacity"
+    assert (slot_of_pair[expert < 0] == n_slots).all()
+    assert ((slot_of_pair >= 0) & (slot_of_pair <= n_slots)).all()
+    assert ((pair_of_slot >= 0) & (pair_of_slot <= n_pairs)).all()
+    assert (pair_of_slot == n_pairs).sum() == n_slots - (expert >= 0).sum()
+
+
+def test_slot_maps_of_stacked_groups_match_each_group():
+    cfg = RoutingConfig(8, top_k=2, capacity=8, steal_attempts=2)
+    tbl = expert_steal_table(TOPO, np.arange(8) * 2, "dfwspt")
+    rs = [route(_logits(t=64, e=8, skew=[0, 1], seed=s), cfg, tbl)
+          for s in (3, 4, 5)]
+    stacked = slot_maps(jnp.stack([r["expert"] for r in rs]),
+                        jnp.stack([r["slot"] for r in rs]), 8, 8)
+    for i, r in enumerate(rs):
+        for got, want in zip(stacked, slot_maps(r["expert"], r["slot"], 8, 8)):
+            np.testing.assert_array_equal(np.asarray(got[i]),
+                                          np.asarray(want))
 
 
 @settings(max_examples=15, deadline=None)

@@ -112,8 +112,8 @@ def matrix_ops(text: str):
 
 CASES = [(a, m) for a in EXPECTED for m in MODES]
 # A MoE decode step of a small batch routes one token group. XLA's
-# batch-dot simplification then rebuilds the one-hot and expert dots,
-# whose group axis is 1, without metadata: they carry no op_name at all.
+# batch-dot simplification may then rebuild the expert dots, whose group
+# axis is 1, without metadata: such dots carry no op_name at all.
 ONE_GROUP = [("granite-moe-1b-a400m", "decode")]
 
 
