@@ -96,8 +96,7 @@ def moe_locality(report, quick=False):
         tbl = (expert_steal_table(topo, np.arange(E), policy)
                if policy != "none" else None)
         cfg = RoutingConfig(E, top_k=1, capacity=T // E,
-                            steal_attempts=attempts,
-                            policy=policy if policy != "none" else "dfwspt")
+                            steal_attempts=attempts)
         fn = jax.jit(lambda lg: route(lg, cfg, tbl))
         us = _timeit(fn, logits)
         r = fn(logits)

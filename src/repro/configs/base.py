@@ -54,7 +54,6 @@ class ArchConfig:
     qk_norm: bool = False
     rope_theta: float = 1e6
     attn_window: int | None = None
-    attn_impl: str = "ref"           # 'ref' | 'kernel'
     # KV head replication for TP > kv_heads (launcher sets per mesh):
     # k/v are repeated this many times before use/caching so the stored
     # head dim divides the model axis (standard GQA tensor-parallel trade).
@@ -91,7 +90,6 @@ class ArchConfig:
     moe_d_ff: int = 0
     capacity_factor: float = 1.25
     moe_group: int = 4096
-    moe_impl: str = "einsum"         # 'einsum' | 'kernel'
     moe_shared_expert: bool = False
     moe_steal_attempts: int = 2      # paper technique; 0 = vanilla drops
     moe_steal_policy: str = "dfwspt"
@@ -103,7 +101,6 @@ class ArchConfig:
     ssm_conv: int = 4
     ssm_expand: int = 2
     ssm_chunk: int = 128
-    ssm_impl: str = "ref"
 
     # sharding profile: '2d' (TP+FSDP) | 'ep_only' (experts on "model",
     # dense FSDP across both axes — for small-d_model MoE; §Perf)

@@ -10,9 +10,13 @@ traffic on short links instead of letting overflow drop (quality loss) or
 re-shuffle across the whole mesh (bandwidth loss).
 
 Because XLA programs are static, the steal order is baked in ahead of
-time from the topology (``expert_steal_table``) — the DFWSRPT variant
+time from the topology: ``expert_steal_table`` is
+``core/stealing.steal_order_matrix`` with the experts as threads, the
+same victim order the simulator's engines sweep; the DFWSRPT variant
 bakes the random tie-breaks at trace time from a seed, which is exactly
 the paper's "randomly choose its victim" decision frozen per program.
+``launch/mesh`` builds the tables the launchers pass; ``models/layers.moe``
+calls ``route`` and ``slot_maps``.
 
 All shapes are static; everything lowers under pjit/shard_map.
 """
@@ -37,7 +41,7 @@ class RoutingConfig:
     top_k: int
     capacity: int            # per-expert token slots (per routed batch)
     steal_attempts: int = 2  # 0 = vanilla GShard-style drop-on-overflow
-    policy: str = "dfwspt"   # or 'dfwsrpt'
+    policy: str = "dfwspt"   # unread: the steal table carries the order
 
 
 def expert_steal_table(topo: Topology,
@@ -50,22 +54,7 @@ def expert_steal_table(topo: Topology,
     expert_device: (E,) physical device (== core in the topology) owning
     each expert shard.
     """
-    expert_device = np.asarray(expert_device, np.int64)
-    E = expert_device.shape[0]
-    dist = topo.core_distance_matrix()
-    rng = np.random.RandomState(seed)
-    rows = []
-    for e in range(E):
-        others = [x for x in range(E) if x != e]
-        d = dist[expert_device[e], expert_device[others]]
-        if policy == "dfwspt":
-            key = np.lexsort((np.asarray(others), d))
-        elif policy == "dfwsrpt":
-            key = np.lexsort((rng.permutation(E - 1), d))
-        else:
-            raise ValueError(f"unknown policy {policy!r}")
-        rows.append([others[i] for i in key])
-    return np.asarray(rows, np.int64)
+    return steal_order_matrix(topo, expert_device, policy, seed)
 
 
 def _fill_positions(choice: jnp.ndarray, active: jnp.ndarray,
@@ -94,8 +83,8 @@ def route(gate_logits: jnp.ndarray,
 
     Args:
       gate_logits: (T, E) router scores for a routed group.
-      steal_table: (E, E-1) from :func:`expert_steal_table`. Required when
-        ``cfg.steal_attempts > 0``.
+      steal_table: (E, E-1) from :func:`expert_steal_table`; None steals
+        from e+1, e+2, ... mod E. Unused when ``cfg.steal_attempts == 0``.
 
     Returns dict with:
       expert:   (T, K) int32 — final expert of each (token, slot); -1 drop.
@@ -117,7 +106,8 @@ def route(gate_logits: jnp.ndarray,
 
     if cfg.steal_attempts > 0:
         if steal_table is None:
-            raise ValueError("steal_attempts > 0 requires a steal_table")
+            # no topology given: expert e steals from e+1, e+2, ... mod E
+            steal_table = (np.arange(E)[:, None] + np.arange(1, E)) % E
         table = jnp.asarray(steal_table, jnp.int32)         # (E, E-1)
 
     # Flatten (token, k-slot) pairs; earlier k-slots get priority, matching

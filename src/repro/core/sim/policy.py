@@ -38,7 +38,9 @@ consumption therefore depends only on the unit count, which is how the
 five stock schedulers remain bit-exact against the seed fixtures). The
 same plan drives both engines: the Python loop interprets the
 pre-lowered group list, the C kernel walks the flattened
-``group_off/unit_off/victim_off/victims`` arrays.
+``group_off/unit_off/victim_off/victims`` arrays. The groups come from
+:func:`repro.core.stealing.victim_groups`, the one statement of the
+victim orders, from which the MoE router's steal tables are drawn too.
 
 Registering a new scheduler is one call — no engine edits::
 
@@ -54,6 +56,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Sequence
 
+from ..stealing import victim_groups
 from ..topology import Topology, lazy_cache
 
 __all__ = [
@@ -158,48 +161,6 @@ class VictimPlan:
         return self._flat
 
 
-def _victim_groups(victim: str, topo: Topology,
-                   cores: Sequence[int]) -> list[list[list[int]]]:
-    """Build the raw group/unit/victim nesting for one policy."""
-    T = len(cores)
-    dist = topo.core_distance_matrix()
-    core_node = topo.core_node
-    out: list[list[list[int]]] = []
-    for th in range(T):
-        others = [v for v in range(T) if v != th]
-        if victim == "none" or not others:
-            out.append([])
-        elif victim == "random":
-            # one group of singleton units, ascending id — a sweep is one
-            # shuffle of T-1 elements, exactly the stock cilk/wf draw.
-            out.append([[[v] for v in others]])
-        elif victim == "dist_id":
-            order = sorted(others,
-                           key=lambda v: (dist[cores[th], cores[v]], v))
-            out.append([[order]])  # one group, one unit: fully static
-        elif victim == "dist_random":
-            by_d: dict[int, list[int]] = {}
-            for v in others:
-                by_d.setdefault(int(dist[cores[th], cores[v]]), []).append(v)
-            # one group per distance tier (asc), singleton units — one
-            # shuffle per tier of tier-size elements, the DFWSRPT draws.
-            out.append([[[v] for v in by_d[d]] for d in sorted(by_d)])
-        elif victim == "node_hier":
-            by_d = {}
-            for v in others:
-                by_d.setdefault(int(dist[cores[th], cores[v]]), []).append(v)
-            per_thread = []
-            for d in sorted(by_d):
-                by_node: dict[int, list[int]] = {}
-                for v in by_d[d]:
-                    by_node.setdefault(int(core_node[cores[v]]), []).append(v)
-                per_thread.append(list(by_node.values()))
-            out.append(per_thread)
-        else:  # pragma: no cover - guarded by SchedulerSpec validation
-            raise ValueError(f"unknown victim policy {victim!r}")
-    return out
-
-
 def compile_victim_plan(spec: SchedulerSpec, topo: Topology,
                         thread_cores: Sequence[int]) -> VictimPlan:
     """Compile (and cache) the victim plan for a spec on a thread binding.
@@ -228,7 +189,7 @@ def compile_victim_plan(spec: SchedulerSpec, topo: Topology,
             if groups is not None and len(groups) != len(cores):
                 groups = None
         if groups is None:
-            groups = _victim_groups(spec.victim, topo, cores)
+            groups = victim_groups(spec.victim, topo, cores)
             if pcache is not None:
                 pcache.put_victim_groups(pkey, groups)
         plan = VictimPlan(len(cores), groups)

@@ -1,4 +1,9 @@
-"""Public kernel entry points.
+"""Public kernel entry points: standalone Pallas ops.
+
+The model layers do not call these; they run the jnp code of ``ref.py``.
+``tests/test_kernels.py`` holds each op to its ``ref.py`` oracle,
+``tests/test_tpu_compile.py`` compiles them for a described v5e, and
+``chip_smoke.py`` runs them on the chip.
 
 Each op pairs a Pallas forward kernel with a backward pass derived from
 the pure-jnp oracle (``jax.vjp`` of ref.py) via ``jax.custom_vjp`` — the
@@ -6,8 +11,8 @@ kernels stay usable under ``jax.grad`` everywhere. A dedicated attention
 backward kernel would be an optimization, not a semantics change.
 
 ``interpret`` resolution: ``None`` → interpret unless running on TPU, so
-the same model code runs kernels natively on TPU and in interpret mode in
-CPU CI.
+the same code runs kernels natively on TPU and in interpret mode in CPU
+CI.
 
 Shapes that do not tile into a kernel's blocks run the oracle off the
 chip; on a TPU they raise ``ValueError`` instead, so a chip run never

@@ -18,9 +18,11 @@ import numpy as np
 
 from repro.core import placement
 from repro.core import topology as topo_mod
+from repro.core.routing import expert_steal_table
 
 __all__ = ["make_production_mesh", "production_topology",
-           "mesh_steal_table", "coordinator_device", "POD_SHAPE"]
+           "mesh_steal_table", "line_steal_table", "coordinator_device",
+           "POD_SHAPE"]
 
 POD_SHAPE = (16, 16)          # 256 chips per v5e pod (2-D ICI torus)
 
@@ -101,5 +103,19 @@ def mesh_steal_table(mesh, num_experts: int, policy: str = "dfwspt",
         expert_device = reps[np.arange(num_experts) * (M // num_experts)]
     multi_pod = "pod" in axes
     topo = production_topology(multi_pod)
-    from repro.core.routing import expert_steal_table
     return expert_steal_table(topo, expert_device, policy=policy, seed=seed)
+
+
+def line_steal_table(num_experts: int,
+                     policy: str = "dfwspt") -> np.ndarray:
+    """Expert steal order for a step jitted for one device.
+
+    Expert e sits on chip e of a modeled 1 × n ring, n = max(devices, E),
+    so under DFWSPT row e is the others by hop distance min(|i-j|, n-|i-j|),
+    ties by the lower id. Returns the (E, E-1) table for
+    core/routing.route.
+    """
+    import jax
+
+    topo = topo_mod.tpu_pod_2d(1, max(len(jax.devices()), num_experts))
+    return expert_steal_table(topo, np.arange(num_experts), policy=policy)

@@ -21,14 +21,12 @@ import dataclasses
 import time
 
 import jax
-import numpy as np
 
 from repro import configs
 from repro.checkpoint import CheckpointManager
-from repro.core import topology as topo_mod
-from repro.core.routing import expert_steal_table
 from repro.data import PipelineConfig, Prefetcher, TokenPipeline
 from repro.launch.jax_cache import use_persistent_compile_cache
+from repro.launch.mesh import line_steal_table
 from repro.models import model as model_lib
 from repro.optim import (AdamWConfig, accumulate_gradients, adamw_init,
                          adamw_update, compressed_gradients)
@@ -96,13 +94,8 @@ def train(cfg, *, steps: int, global_batch: int, seq_len: int,
           log_every: int = 10) -> list[StepLog]:
     """Run the training loop for ``cfg``; one :class:`StepLog` per step."""
     # paper technique: steal table from the (modeled) topology
-    steal = None
-    if cfg.moe_num_experts:
-        n_dev = max(len(jax.devices()), cfg.moe_num_experts)
-        topo = topo_mod.tpu_pod_2d(1, n_dev) if n_dev > 1 \
-            else topo_mod.uma(cfg.moe_num_experts)
-        owners = np.arange(cfg.moe_num_experts) % topo.num_cores
-        steal = expert_steal_table(topo, owners, cfg.moe_steal_policy)
+    steal = (line_steal_table(cfg.moe_num_experts, cfg.moe_steal_policy)
+             if cfg.moe_num_experts else None)
 
     key = jax.random.PRNGKey(seed)
     params = model_lib.init_params(cfg, key)

@@ -20,7 +20,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.routing import RoutingConfig, route, slot_maps
-from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 
 Params = dict[str, Any]
@@ -60,9 +59,7 @@ def rms_weight(d, dtype):
 # norms / rope
 # ----------------------------------------------------------------------
 
-def rmsnorm(x, w, eps=1e-6, use_kernel=False):
-    if use_kernel:
-        return kops.rmsnorm(x, w, eps)
+def rmsnorm(x, w, eps=1e-6):
     return kref.rmsnorm_ref(x, w, eps)
 
 
@@ -145,29 +142,19 @@ def attention(x, p, cfg, *, positions, cache=None, causal=True):
         new_cache = dict(k=kk, v=vv, length=length + S)
         kv_off = length
 
-    if cfg.attn_impl == "kernel" and cache is None:
-        out = kops.flash_attention(q, kk, vv, causal=causal,
-                                   window=cfg.attn_window)
-    elif S >= cfg.attn_chunk_threshold:
+    if S >= cfg.attn_chunk_threshold:
         # long prefill/training: bound the score slab to (chunk × Skv)
         out = kref.attention_chunked_ref(
             q, kk, vv, causal=causal or cache is not None,
-            window=cfg.attn_window, kv_offset=_kv_offset(kv_off, cache),
-            chunk=cfg.attn_chunk)
+            window=cfg.attn_window, kv_offset=kv_off, chunk=cfg.attn_chunk)
     else:
         # decode path masks positions ≥ length + S via the causal mask on
-        # absolute positions (cache tail is zeros but masked out).
+        # absolute positions (cache tail is zeros but masked out); with a
+        # cache, q's absolute position is the previous length.
         out = kref.attention_ref(q, kk, vv, causal=causal or cache is not None,
-                                 window=cfg.attn_window,
-                                 kv_offset=_kv_offset(kv_off, cache))
+                                 window=cfg.attn_window, kv_offset=kv_off)
     out = out.reshape(B, S, H * Dh) @ p["wo"]
     return out, new_cache
-
-
-def _kv_offset(kv_off, cache):
-    # with a cache, q absolute position = previous length (traced scalar
-    # is fine — attention_ref builds the mask from it)
-    return kv_off
 
 
 def init_cross_attention(key, cfg) -> Params:
@@ -342,8 +329,8 @@ def moe(x, p, cfg, steal_table=None):
 
     Tokens are routed in groups of ``cfg.moe_group`` (GShard-style) so the
     expert slots stay bounded; the router's overflow re-routing walks
-    the topology steal table (the paper's scheduler, see core/routing.py).
-    Returns (y, aux_loss).
+    the topology steal table (the paper's scheduler, see core/routing.py),
+    or routing's own order when given none. Returns (y, aux_loss).
     """
     B, S, D = x.shape
     E = cfg.moe_num_experts
@@ -357,19 +344,11 @@ def moe(x, p, cfg, steal_table=None):
     capacity = max(capacity, cfg.moe_top_k)
     rcfg = RoutingConfig(num_experts=E, top_k=cfg.moe_top_k,
                          capacity=capacity,
-                         steal_attempts=cfg.moe_steal_attempts,
-                         policy=cfg.moe_steal_policy)
-
-    table = steal_table
-    if rcfg.steal_attempts > 0 and table is None:
-        # fallback: ring order (expert e steals from e±1, e±2, ...)
-        idx = np.arange(E)
-        table = np.stack([np.concatenate([
-            (e + np.arange(1, E)) % E]) for e in idx])
+                         steal_attempts=cfg.moe_steal_attempts)
 
     def route_group(xg1):
         logits = xg1.astype(jnp.float32) @ p["router"]
-        r = route(logits, rcfg, table)
+        r = route(logits, rcfg, steal_table)
         return r["expert"], r["slot"], r["weight"], r["aux_loss"]
 
     # routing per group (small tensors) …
@@ -385,20 +364,11 @@ def moe(x, p, cfg, steal_table=None):
         xin = _dispatch(xg, pair, slot_of_pair).swapaxes(0, 1)
         xin = _constrain(xin, cfg.moe_xin_spec)                # (g,E,C,D)
     with jax.named_scope("moe.experts"):
-        if cfg.moe_impl == "kernel":
-            flat = xin.reshape(ngroups * E, capacity, D)
-            wg_f = jnp.tile(p["wg"], (ngroups, 1, 1))
-            wu_f = jnp.tile(p["wu"], (ngroups, 1, 1))
-            wd_f = jnp.tile(p["wd"], (ngroups, 1, 1))
-            h = (jax.nn.silu(kops.moe_gmm(flat, wg_f))
-                 * kops.moe_gmm(flat, wu_f))
-            eout = kops.moe_gmm(h, wd_f).reshape(ngroups, E, capacity, D)
-        else:
-            h = jnp.einsum("gecd,edf->gecf", xin, p["wg"])
-            u = jnp.einsum("gecd,edf->gecf", xin, p["wu"])
-            h = jax.nn.silu(h) * u
-            h = _constrain(h, cfg.moe_h_spec)
-            eout = jnp.einsum("gecf,efd->gecd", h, p["wd"])
+        h = jnp.einsum("gecd,edf->gecf", xin, p["wg"])
+        u = jnp.einsum("gecd,edf->gecf", xin, p["wu"])
+        h = jax.nn.silu(h) * u
+        h = _constrain(h, cfg.moe_h_spec)
+        eout = jnp.einsum("gecf,efd->gecd", h, p["wd"])
         eout = _constrain(eout, cfg.moe_xin_spec)
     with jax.named_scope("moe.combine"):
         y = _combine(eout.swapaxes(0, 1), weight, pair,
@@ -459,8 +429,8 @@ def _causal_conv(xbc, w, b, conv_state=None):
 def mamba(x, p, cfg, cache=None):
     """Mamba2 block. cache: None | dict(conv, ssm) for decode.
 
-    Training/prefill: chunked SSD (kernel or ref). Decode (S == 1):
-    single-step recurrence.
+    Training/prefill: chunked SSD. Decode (S == 1): single-step
+    recurrence.
     """
     B, S, D = x.shape
     d_inner, G, N, H = _mamba_split(cfg)
@@ -484,12 +454,7 @@ def mamba(x, p, cfg, cache=None):
         x_dt = _constrain(x_dt, cfg.ssm_act_spec)
 
         if cache is None:
-            if cfg.ssm_impl == "kernel":
-                y, _ = kops.ssd_scan(x_dt, a, bmat, cmat,
-                                     chunk=cfg.ssm_chunk)
-            else:
-                y = kref.ssd_chunked_ref(x_dt, a, bmat, cmat,
-                                         chunk=cfg.ssm_chunk)
+            y = kref.ssd_chunked_ref(x_dt, a, bmat, cmat, chunk=cfg.ssm_chunk)
             new_cache = None
         elif S > 1:
             # chunked prefill with carried state

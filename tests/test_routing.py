@@ -9,6 +9,8 @@ from _hypothesis_compat import given, settings, strategies as st
 from repro.core import topology
 from repro.core.routing import (RoutingConfig, expert_steal_table, route,
                                 slot_maps)
+from repro.core.stealing import steal_order_matrix
+from repro.launch.mesh import line_steal_table
 
 TOPO = topology.tpu_pod_2d(4, 4)
 TABLE = expert_steal_table(TOPO, np.arange(16), "dfwspt")
@@ -36,6 +38,41 @@ def test_dfwsrpt_randomizes_ties_only():
     for e in range(16):
         assert [d[e, v] for v in t1[e]] == [d[e, v] for v in t2[e]]
     assert (t1 != t2).any()        # ties actually shuffled
+
+
+@pytest.mark.parametrize("policy", ["dfwspt", "dfwsrpt", "dfwshier"])
+def test_expert_steal_table_is_the_victim_order_of_the_owners(policy):
+    """Experts are the stealing module's threads, on their owners' cores:
+    two experts per device here, so each one's first victim is the other
+    expert of its own device."""
+    owners = np.arange(16) // 2
+    table = expert_steal_table(TOPO, owners, policy, seed=5)
+    np.testing.assert_array_equal(
+        table, steal_order_matrix(TOPO, owners, policy, seed=5))
+    assert (table[:, 0] == np.arange(16) ^ 1).all()
+
+
+@pytest.mark.parametrize("E", [8, 32])
+def test_line_table_is_the_torus_rule(E):
+    """The table a one-device step routes by: the others by hop distance
+    min(|i-j|, E-|i-j|) on a ring of E chips, ties by the lower id."""
+    want = [sorted((j for j in range(E) if j != i),
+                   key=lambda j: (min(abs(i - j), E - abs(i - j)), j))
+            for i in range(E)]
+    assert line_steal_table(E).tolist() == want
+
+
+def test_route_without_table_walks_the_ring():
+    cfg = RoutingConfig(16, top_k=2, capacity=8, steal_attempts=3)
+    logits = _logits(t=128, skew=[0, 1], seed=4)
+    ring = np.stack([(e + np.arange(1, 16)) % 16 for e in range(16)])
+    got, want = route(logits, cfg), route(logits, cfg, ring)
+    for key in ("expert", "slot", "weight"):
+        np.testing.assert_array_equal(np.asarray(got[key]),
+                                      np.asarray(want[key]))
+    top = np.asarray(jax.lax.top_k(logits, 2)[1])
+    expert = np.asarray(got["expert"])
+    assert ((expert >= 0) & (expert != top)).any(), "no pair was stolen"
 
 
 def test_no_overflow_no_steals():

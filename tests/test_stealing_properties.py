@@ -112,17 +112,12 @@ def test_dfwshier_node_members_contiguous(topo_i, T, thread_raw, seed):
         assert vs == sorted(vs)
 
 
-@settings(max_examples=20, deadline=None)
-@given(topo_i=st.integers(0, len(TOPOS) - 1), T=st.integers(2, 16),
-       thread_raw=st.integers(0, 15), seed=st.integers(0, 5))
-def test_dfwshier_matches_compiled_plan_sweep(topo_i, T, thread_raw, seed):
-    """victim_order('dfwshier') from a fresh RandomState(seed) equals
-    the engine's first sweep of the compiled VictimPlan for that seed —
-    the ahead-of-time form and the simulator agree."""
+def _plan_sweep(policy, topo, cores, thread, seed):
+    """The Python engine's first sweep of the compiled VictimPlan, drawn
+    from a fresh RandomState(seed) as the engine draws it."""
     from repro.core.sim import SCHEDULERS
     from repro.core.sim.policy import compile_victim_plan
-    topo, cores, thread, _ = _setup(topo_i, T, thread_raw, seed)
-    plan = compile_victim_plan(SCHEDULERS["dfwshier"], topo, cores)
+    plan = compile_victim_plan(SCHEDULERS[policy], topo, cores)
     rng = np.random.RandomState(seed)
     swept = []
     for tag, payload in plan.py_groups[thread]:
@@ -137,9 +132,41 @@ def test_dfwshier_matches_compiled_plan_sweep(topo_i, T, thread_raw, seed):
             rng.shuffle(units)
             for u in units:
                 swept.extend(u)
+    return swept
+
+
+@settings(max_examples=20, deadline=None)
+@given(topo_i=st.integers(0, len(TOPOS) - 1), T=st.integers(2, 16),
+       thread_raw=st.integers(0, 15), seed=st.integers(0, 5))
+def test_dfwshier_matches_compiled_plan_sweep(topo_i, T, thread_raw, seed):
+    """victim_order('dfwshier') from a fresh RandomState(seed) equals
+    the engine's first sweep of the compiled VictimPlan for that seed —
+    the ahead-of-time form and the simulator agree."""
+    topo, cores, thread, _ = _setup(topo_i, T, thread_raw, seed)
     got = victim_order(topo, cores, thread, "dfwshier",
                        np.random.RandomState(seed))
-    assert got == swept
+    assert got == _plan_sweep("dfwshier", topo, cores, thread, seed)
+
+
+# (topology index, T, thread, seed): every topology, a thread at either
+# end and inside, T below and at the topology's size
+SWEEP_EXAMPLES = [(0, 16, 0, 0), (0, 16, 7, 3), (0, 9, 8, 5), (1, 8, 0, 1),
+                  (1, 8, 5, 4), (1, 6, 3, 2), (2, 8, 2, 0), (2, 5, 4, 5)]
+
+
+@pytest.mark.parametrize("policy", POLICIES)
+def test_victim_order_matches_compiled_plan_sweep(policy):
+    """For each paper policy, the router's order (victim_order, and row
+    ``thread`` of steal_order_matrix when it is the first row drawn) is
+    the simulator engine's first sweep."""
+    for topo_i, T, thread, seed in SWEEP_EXAMPLES:
+        topo, cores, thread, _ = _setup(topo_i, T, thread, seed)
+        want = _plan_sweep(policy, topo, cores, thread, seed)
+        assert victim_order(topo, cores, thread, policy,
+                            np.random.RandomState(seed)) == want
+        if thread == 0:
+            row = steal_order_matrix(topo, cores, policy, seed=seed)[0]
+            assert row.tolist() == want
 
 
 @pytest.mark.parametrize("policy", POLICIES)
