@@ -57,22 +57,36 @@ def expert_steal_table(topo: Topology,
     return steal_order_matrix(topo, expert_device, policy, seed)
 
 
-def _fill_positions(choice: jnp.ndarray, active: jnp.ndarray,
-                    used: jnp.ndarray, num_experts: int, capacity: int):
+def _fill_positions(pick: jnp.ndarray, active: jnp.ndarray,
+                    used: jnp.ndarray, capacity: int):
     """Greedy in-order capacity fill for one routing attempt.
 
-    choice: (T,) expert id per token; active: (T,) tokens still waiting.
-    used: (E,) slots already taken. Returns (placed, position, new_used).
+    pick: (N, E) bool, each pair's chosen expert; active: (N,) pairs still
+    waiting. used: (E,) slots already taken. Returns (placed, position,
+    new_used).
+
+    A pair's position in its expert's queue is the count of earlier active
+    pairs with the same expert. ``jnp.cumsum`` along N lowers to a
+    reduce-window over the whole axis; here the count is a lower-triangular
+    0/1 matmul within blocks of b pairs (exact: bf16 operands, f32 sums of
+    at most 256 ones) plus the running total of the blocks before. Lookups
+    into (E,) tables are sums over the pair's one-hot row.
     """
-    onehot = jax.nn.one_hot(choice, num_experts, dtype=jnp.int32)
-    onehot = onehot * active[:, None].astype(jnp.int32)
-    # position of each token within its chosen expert's queue
-    pos_in_expert = jnp.cumsum(onehot, axis=0) - onehot   # (T, E)
-    pos = jnp.take_along_axis(
-        pos_in_expert, choice[:, None], axis=1)[:, 0] + used[choice]
+    n, e = pick.shape
+    b = min(256, -(-n // 8) * 8)   # a decode group of a few tokens: one block
+    nb = -(-n // b)
+    onehot = pick & active[:, None]
+    blk = jnp.pad(onehot, ((0, nb * b - n), (0, 0))).reshape(nb, b, e)
+    upto = jnp.einsum("ij,kje->kie", jnp.tri(b, dtype=jnp.bfloat16),
+                      blk.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32).astype(jnp.int32)
+    totals = upto[:, -1]                                  # (nb, E)
+    before = jnp.cumsum(totals, axis=0) - totals
+    count = (upto + before[:, None]).reshape(nb * b, e)[:n] - onehot
+    # position of each pair within its chosen expert's queue
+    pos = jnp.sum(jnp.where(pick, count + used, 0), axis=1)
     placed = active & (pos < capacity)
-    new_used = used + jnp.minimum(onehot.sum(axis=0),
-                                  capacity - used)
+    new_used = used + jnp.minimum(totals.sum(axis=0), capacity - used)
     return placed, pos, new_used
 
 
@@ -120,15 +134,16 @@ def route(gate_logits: jnp.ndarray,
 
     choice = flat_e
     for attempt in range(cfg.steal_attempts + 1):
-        placed, pos, used = _fill_positions(choice, flat_active, used,
-                                            E, cfg.capacity)
+        pick = choice[:, None] == jnp.arange(E)             # (K*T, E)
+        placed, pos, used = _fill_positions(pick, flat_active, used,
+                                            cfg.capacity)
         flat_expert = jnp.where(placed, choice, flat_expert)
-        flat_slot = jnp.where(placed, pos.astype(jnp.int32), flat_slot)
+        flat_slot = jnp.where(placed, pos, flat_slot)
         flat_active = flat_active & ~placed
         if attempt < cfg.steal_attempts:
             # overflow tokens walk the victim list of their *current*
             # expert: nearest device first (DFWSPT/DFWSRPT).
-            choice = table[choice, attempt]
+            choice = jnp.sum(jnp.where(pick, table[:, attempt], 0), axis=1)
     expert = flat_expert.reshape(cfg.top_k, T).T            # (T, K)
     slot = flat_slot.reshape(cfg.top_k, T).T
     keep = expert >= 0

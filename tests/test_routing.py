@@ -1,6 +1,7 @@
 """Locality-aware MoE routing tests (the paper's scheduler, in-graph)."""
 
 import jax
+import jax.extend as jex
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -125,6 +126,128 @@ def test_stolen_tokens_go_to_nearest_free():
     moved = e[(e >= 0) & (e != 0)]
     assert set(moved.tolist()) <= {int(TABLE[0, 0])}
     assert (e == 0).sum() == 8     # expert 0 exactly at capacity
+
+
+def _route_by_cumsum(gate_logits, cfg, steal_table=None):
+    """``route``'s placement as first written: each attempt counts a pair's
+    place in its expert's queue by a cumsum along the pairs, and reads the
+    (E,) tables by element gathers. Returns route's arrays and the pairs
+    each attempt placed."""
+    T, E = gate_logits.shape
+    probs = jax.nn.softmax(gate_logits.astype(jnp.float32), axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, cfg.top_k)
+    if steal_table is None:
+        steal_table = (np.arange(E)[:, None] + np.arange(1, E)) % E
+    table = jnp.asarray(steal_table, jnp.int32)
+    choice = top_e.T.reshape(-1)
+    active = jnp.ones(choice.shape, bool)
+    expert = jnp.full(choice.shape, -1, jnp.int32)
+    slot = jnp.full(choice.shape, -1, jnp.int32)
+    used = jnp.zeros((E,), jnp.int32)
+    placed_per_attempt = []
+    for attempt in range(cfg.steal_attempts + 1):
+        onehot = (jax.nn.one_hot(choice, E, dtype=jnp.int32)
+                  * active[:, None].astype(jnp.int32))
+        pos_in_expert = jnp.cumsum(onehot, axis=0) - onehot
+        pos = jnp.take_along_axis(
+            pos_in_expert, choice[:, None], axis=1)[:, 0] + used[choice]
+        placed = active & (pos < cfg.capacity)
+        used = used + jnp.minimum(onehot.sum(axis=0), cfg.capacity - used)
+        expert = jnp.where(placed, choice, expert)
+        slot = jnp.where(placed, pos, slot)
+        active = active & ~placed
+        placed_per_attempt.append(placed.sum())
+        if attempt < cfg.steal_attempts:
+            choice = table[choice, attempt]
+    expert = expert.reshape(cfg.top_k, T).T
+    slot = slot.reshape(cfg.top_k, T).T
+    keep = expert >= 0
+    w = top_p * keep
+    w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+    return (dict(expert=expert, slot=slot, weight=w,
+                 drop_fraction=1.0 - jnp.mean(keep.astype(jnp.float32))),
+            jnp.stack(placed_per_attempt))
+
+
+def _fill_case(e, k, t, cap_factor, attempts, seed=0):
+    """Logits that pile onto every fourth expert, the first most, so their
+    nearest victims have room; capacity ``cap_factor`` x the even share of
+    the T·K pairs."""
+    cfg = RoutingConfig(e, top_k=k, steal_attempts=attempts,
+                        capacity=max(1, int(np.ceil(cap_factor * t * k / e))))
+    x = jax.random.normal(jax.random.PRNGKey(seed), (t, e))
+    return cfg, x.at[:, ::4].add(jnp.linspace(6.0, 3.0, len(range(0, e, 4))))
+
+
+def _assert_same_fill(got, want):
+    for key in ("expert", "slot", "weight", "drop_fraction"):
+        np.testing.assert_array_equal(np.asarray(got[key]),
+                                      np.asarray(want[key]), err_msg=key)
+
+
+@pytest.mark.parametrize("table", [None, "line"])
+@pytest.mark.parametrize("attempts", [0, 1, 2])
+@pytest.mark.parametrize("cap_factor", [1.25, 0.5])
+@pytest.mark.parametrize("t", [8, 200, 4096])
+@pytest.mark.parametrize("e,k", [(8, 2), (16, 1), (32, 8)])
+def test_route_places_every_pair_where_the_cumsum_fill_did(
+        e, k, t, cap_factor, attempts, table):
+    cfg, x = _fill_case(e, k, t, cap_factor, attempts, seed=t + attempts)
+    tbl = line_steal_table(e) if table else None
+    want, placed = _route_by_cumsum(x, cfg, tbl)
+    _assert_same_fill(route(x, cfg, tbl), want)
+    if cap_factor < 1:
+        assert float(want["drop_fraction"]) > 0
+        if t > 8:   # 8 tokens are too few pairs to overflow three times
+            assert (np.asarray(placed) > 0).all(), np.asarray(placed)
+
+
+@pytest.mark.parametrize("cap_factor", [1.25, 0.5])
+@pytest.mark.parametrize("t", [8, 200, 4096])
+@pytest.mark.parametrize("e,k", [(8, 2), (16, 1), (32, 8)])
+def test_route_under_vmap_fills_each_group_as_the_cumsum_fill(
+        e, k, t, cap_factor):
+    """Groups routed side by side, as ``layers.moe`` vmaps them."""
+    cfg, x0 = _fill_case(e, k, t, cap_factor, 2, seed=1)
+    _, x1 = _fill_case(e, k, t, cap_factor, 2, seed=2)
+    tbl = line_steal_table(e)
+    got = jax.vmap(lambda x: route(x, cfg, tbl))(jnp.stack([x0, x1]))
+    for g, x in enumerate((x0, x1)):
+        want, _ = _route_by_cumsum(x, cfg, tbl)
+        _assert_same_fill({key: v[g] for key, v in got.items()}, want)
+
+
+def _eqns(jaxpr):
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                if isinstance(sub, jex.core.ClosedJaxpr):
+                    yield from _eqns(sub.jaxpr)
+                elif isinstance(sub, jex.core.Jaxpr):
+                    yield from _eqns(sub)
+
+
+def test_route_neither_cumsums_nor_gathers_along_the_pairs():
+    """At granite's group (T 4096, K 8, E 32) a cumsum along the K·T pairs
+    lowers to a reduce-window over the whole axis, and a gather of one
+    element a pair runs far below bandwidth on a TPU: the fill has
+    neither."""
+    t, k, e = 4096, 8, 32
+    cfg = RoutingConfig(e, top_k=k, capacity=1280, steal_attempts=2)
+    tbl = line_steal_table(e)
+    jaxpr = jax.make_jaxpr(lambda x: route(x, cfg, tbl))(
+        jnp.zeros((t, e), jnp.float32)).jaxpr
+    eqns = list(_eqns(jaxpr))
+    assert any(q.primitive.name == "dot_general" for q in eqns)
+    for q in eqns:
+        name = q.primitive.name
+        if name.startswith("cum"):
+            shape = q.invars[0].aval.shape
+            assert shape[q.params["axis"]] != t * k, (name, shape)
+        if name == "gather":
+            idx = q.invars[1].aval.shape
+            assert int(np.prod(idx[:-1])) != t * k, (name, idx)
 
 
 def _maps(cap=8, attempts=2, seed=3):
