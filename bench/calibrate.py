@@ -46,11 +46,12 @@ def half_batch():
 
 
 def train_readings(cfg, fcfg, mix, seed, control, fault):
-    from bench import check
+    from bench import check, kinds
     from bench.drivers import train
     from bench.reference.model import Numerics
 
-    st = train.State(cfg, mix, seed)
+    kind = kinds.kind(fcfg)
+    st = train.State(cfg, kind, mix, seed)
     prog = st.checked(mix["checked_steps"])
     st.close()
     ref = train.reference(fcfg, mix, st.init, st.key, st.pool)
@@ -62,7 +63,7 @@ def train_readings(cfg, fcfg, mix, seed, control, fault):
     if fault == "half_batch":
         undo = half_batch()
         try:
-            fs = train.State(cfg, mix, seed)
+            fs = train.State(cfg, kind, mix, seed)
             bad = fs.checked(mix["checked_steps"])
             fs.close()
         finally:
@@ -74,15 +75,15 @@ def train_readings(cfg, fcfg, mix, seed, control, fault):
 def serve_readings(cfg, fcfg, mix, seed, control, fault):
     import numpy as np
 
-    from bench import check, weights
+    from bench import check, kinds, weights
     from bench.drivers import serve
-    from bench.reference import model as ref_model
     from bench.reference import run as ref_run
     from bench.reference.model import Numerics
     from bench.traffic import gen
 
+    kind = kinds.kind(fcfg)
     key = weights.seed_key(seed)
-    init = weights.make_init(weights.layout(cfg))
+    init = weights.make_init(weights.layout(cfg), kind)
     params = init(key)
     zipf = gen.Zipf(seed, cfg.vocab_size, mix["zipf_s"])
     done = {}
@@ -97,16 +98,16 @@ def serve_readings(cfg, fcfg, mix, seed, control, fault):
                                                   mix["gen"])[0])
         i += 1
     del params
-    spec = ref_model.spec_from_file(fcfg)
-    table = ref_model.ring_table(spec.E) if spec.kind == "moe" else None
+    spec = kind.spec_from_file(fcfg)
+    table = kind.serve_table(spec)
     params = init(key)
     gaps = {"program": [], "control": []}
     for P, (prompts, tokens) in done.items():
         seq = np.concatenate([prompts, tokens[:, :-1]], 1)
-        ref = ref_run.serve_logits(params, spec, seq, P, table)
+        ref = ref_run.serve_logits(params, kind, spec, seq, P, table)
         gaps["program"].append(check.token_gaps(ref, tokens).ravel())
         if control:
-            low = ref_run.serve_logits(params, spec, seq, P, table,
+            low = ref_run.serve_logits(params, kind, spec, seq, P, table,
                                        Numerics(fp8=True))
             gaps["control"].append(
                 check.token_gaps(ref, low.argmax(-1)).ravel())

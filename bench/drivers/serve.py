@@ -19,9 +19,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from bench import check, flops, harness, weights
+from bench import check, flops, harness, kinds, weights
 from bench.harness import span
-from bench.reference import model as ref_model
 from bench.reference import run as ref_run
 from bench.traffic import gen
 
@@ -71,8 +70,9 @@ def serve_batch(prefill, decode, params, prompts, gen_len: int):
 def run(cfg, fcfg: dict, mix: dict, seed: int, seconds: float, traced: bool,
         limits: dict, cell: dict, t_start: float) -> dict:
     B, gen_len = mix["batch"], mix["gen"]
+    kind = kinds.kind(fcfg)
     key = weights.seed_key(seed)
-    init = weights.make_init(weights.layout(cfg))
+    init = weights.make_init(weights.layout(cfg), kind)
     params = init(key)
     progs = {P: programs(cfg, params, B, P, gen_len)
              for P in mix["prompt_lens"]}
@@ -101,14 +101,14 @@ def run(cfg, fcfg: dict, mix: dict, seed: int, seconds: float, traced: bool,
     del params
 
     # the reference over a sample of finished batches, the longest in it
-    spec = ref_model.spec_from_file(fcfg)
-    table = ref_model.ring_table(spec.E) if spec.kind == "moe" else None
+    spec = kind.spec_from_file(fcfg)
+    table = kind.serve_table(spec)
     ref_params = init(key)
     gaps = []
     for j in sample(done, seed, mix["checked_batches"]):
         P, prompts, tokens = done[j][:3]
         seq = np.concatenate([prompts, tokens[:, :-1]], 1)
-        ref = ref_run.serve_logits(ref_params, spec, seq, P, table)
+        ref = ref_run.serve_logits(ref_params, kind, spec, seq, P, table)
         gaps.append(check.token_gaps(ref, tokens).ravel())
     numbers = check.serve_numbers(np.concatenate(gaps))
     harness.write_json(f"{cell['name']}.{seed}.{int(traced)}.requests.json", [
@@ -125,7 +125,7 @@ def run(cfg, fcfg: dict, mix: dict, seed: int, seconds: float, traced: bool,
               "checks": checks, "numbers": numbers}
     if traced:
         result["ctx"] = {"trace": win.reduced_trace(), "peaks": None,
-                         "work": executions(cfg, done, B, gen_len)}
+                         "work": executions(kind, cfg, done, B, gen_len)}
         return result
     end = win.deadline
     ttft = [t[0] - s for _, _, _, s, t in done for _ in range(B)]
@@ -164,11 +164,11 @@ def sample(done: list, seed: int, n: int) -> list[int]:
     return picked
 
 
-def executions(cfg, done: list, B: int, gen_len: int) -> dict:
+def executions(kind, cfg, done: list, B: int, gen_len: int) -> dict:
     """Model work of every prefill and decode call the window made."""
     pre, dec = [], []
     for P, *_ in done:
-        pre.append(flops.prefill(cfg, B, P))
-        dec.extend(flops.decode_step(cfg, B, P + j)
+        pre.append(flops.prefill(kind, cfg, B, P))
+        dec.extend(flops.decode_step(kind, cfg, B, P + j)
                    for j in range(gen_len - 1))
     return {"prefill": pre, "decode": dec}

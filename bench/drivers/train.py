@@ -18,25 +18,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from bench import check, flops, harness, weights
+from bench import check, flops, harness, kinds, weights
 from bench.harness import span
 from bench.reference import model as ref_model
 from bench.reference import run as ref_run
 from bench.traffic import gen
-
-
-def steal_table(cfg):
-    """The steal table ``train()`` builds (mirrors launch/train.py:101-107)."""
-    from repro.core import topology as topo_mod
-    from repro.core.routing import expert_steal_table
-
-    if not cfg.moe_num_experts:
-        return None
-    n_dev = max(len(jax.devices()), cfg.moe_num_experts)
-    topo = topo_mod.tpu_pod_2d(1, n_dev) if n_dev > 1 \
-        else topo_mod.uma(cfg.moe_num_experts)
-    owners = np.arange(cfg.moe_num_experts) % topo.num_cores
-    return expert_steal_table(topo, owners, cfg.moe_steal_policy)
 
 
 def opt_config(mix: dict):
@@ -53,20 +39,23 @@ def opt_config(mix: dict):
 class State:
     """The compiled step with its state and feed, built once in set-up."""
 
-    def __init__(self, cfg, mix: dict, seed: int):
+    def __init__(self, cfg, kind, mix: dict, seed: int):
         from repro.data import Prefetcher
+        from repro.launch.mesh import line_steal_table
         from repro.launch.train import build_train_step
         from repro.optim import adamw_init
 
         self.opt_cfg = opt_config(mix)
         self.key = weights.seed_key(seed)
-        self.init = weights.make_init(weights.layout(cfg))
+        self.init = weights.make_init(weights.layout(cfg), kind)
         self.params = self.init(self.key)
         self.opt_state = jax.jit(lambda p: adamw_init(p, self.opt_cfg))(
             self.params)
         self.comp_state = None
-        self.step_fn = jax.jit(build_train_step(cfg, self.opt_cfg, 1,
-                                                steal_table(cfg)))
+        # the steal table train() builds
+        table = (line_steal_table(cfg.moe_num_experts, cfg.moe_steal_policy)
+                 if cfg.moe_num_experts else None)
+        self.step_fn = jax.jit(build_train_step(cfg, self.opt_cfg, 1, table))
         self.pool = gen.train_pool(mix, cfg.vocab_size, seed)
         self.it = Prefetcher(itertools.cycle(self.pool))
 
@@ -107,18 +96,20 @@ class State:
 def reference(fcfg: dict, mix: dict, init, key, pool,
               num=ref_model.Numerics()) -> dict:
     """The reference's first steps from the same seed and rows."""
-    spec = ref_model.spec_from_file(fcfg)
-    table = ref_model.torus_table(spec.E) if spec.kind == "moe" else None
+    kind = kinds.kind(fcfg)
+    spec = kind.spec_from_file(fcfg)
     opt = {k: mix[k] for k in ("lr", "warmup_steps", "total_steps",
                                "lr_min_ratio", "b1", "b2", "eps",
                                "weight_decay", "clip_norm")}
-    return ref_run.train_steps(init(key), spec, pool[:mix["checked_steps"]],
-                               opt, table, num)
+    return ref_run.train_steps(init(key), kind, spec,
+                               pool[:mix["checked_steps"]], opt,
+                               kind.train_table(spec), num)
 
 
 def run(cfg, fcfg: dict, mix: dict, seed: int, seconds: float, traced: bool,
         limits: dict, cell: dict, t_start: float) -> dict:
-    st = State(cfg, mix, seed)
+    kind = kinds.kind(fcfg)
+    st = State(cfg, kind, mix, seed)
     prog = st.checked(mix["checked_steps"])
     setup_s = time.perf_counter() - t_start
     steps, loss = 0, float("nan")
@@ -143,7 +134,7 @@ def run(cfg, fcfg: dict, mix: dict, seed: int, seconds: float, traced: bool,
     if traced:
         result["ctx"] = {"trace": win.reduced_trace(), "peaks": None,
                          "work": {"train_step": flops.train_step(
-                             cfg, mix["batch"], mix["seq_len"])}}
+                             kind, cfg, mix["batch"], mix["seq_len"])}}
     else:
         tokens = steps * mix["batch"] * mix["seq_len"]
         result["metrics"] = {"train_tokens_per_s": {

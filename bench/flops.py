@@ -87,46 +87,25 @@ def cross_entropy(T, V) -> Work:
     return Work(6 * T * V, T * V * F32)
 
 
-def forward(cfg, B, S, start=0, head_tokens=None, carried=False) -> Work:
+def forward(kind, cfg, B, S, start=0, head_tokens=None, carried=False) -> Work:
     """One forward pass over B rows of S tokens after ``start`` earlier
-    positions; ``head_tokens`` tokens reach the head (default all)."""
-    T = B * S
-    w = Work()
-    for kind, ffn in cfg.pattern:
-        layer = rmsnorm(T, cfg.d_model)
-        if kind == "attn":
-            layer += attention(B, S, start, cfg.d_model, cfg.num_heads,
-                               cfg.num_kv_heads, cfg.head_dim)
-        elif kind == "mamba":
-            d_inner = cfg.ssm_expand * cfg.d_model
-            layer += mamba_mixer(B, S, cfg.d_model, d_inner,
-                                 d_inner // cfg.ssm_head_dim, cfg.ssm_state,
-                                 cfg.ssm_head_dim, cfg.ssm_groups,
-                                 cfg.ssm_conv, carried)
-        else:
-            raise ValueError(f"no count for layer kind {kind!r}")
-        if ffn == "moe":
-            layer += rmsnorm(T, cfg.d_model) + moe_experts(
-                T, cfg.d_model, cfg.moe_d_ff, cfg.moe_num_experts,
-                cfg.moe_top_k)
-        elif ffn != "none":
-            raise ValueError(f"no count for ffn kind {ffn!r}")
-        w += layer * cfg.repeats
-    ht = T if head_tokens is None else head_tokens
-    return w + rmsnorm(ht, cfg.d_model) + head(ht, cfg.d_model,
-                                               cfg.vocab_size)
+    positions; ``head_tokens`` tokens reach the head (default all). The
+    layers are counted by the model's kind (``bench/kinds/``)."""
+    ht = B * S if head_tokens is None else head_tokens
+    return kind.work(cfg, B, S, start, carried) + rmsnorm(
+        ht, cfg.d_model) + head(ht, cfg.d_model, cfg.vocab_size)
 
 
-def train_step(cfg, B, S) -> Work:
+def train_step(kind, cfg, B, S) -> Work:
     """Forward, loss and backward (twice the forward's operations)."""
-    fwd = forward(cfg, B, S) + cross_entropy(B * S, cfg.vocab_size)
+    fwd = forward(kind, cfg, B, S) + cross_entropy(B * S, cfg.vocab_size)
     return Work(3 * fwd.flops, 3 * fwd.bytes)
 
 
-def prefill(cfg, B, S) -> Work:
-    return forward(cfg, B, S, head_tokens=B)
+def prefill(kind, cfg, B, S) -> Work:
+    return forward(kind, cfg, B, S, head_tokens=B)
 
 
-def decode_step(cfg, B, length) -> Work:
+def decode_step(kind, cfg, B, length) -> Work:
     """One token per row after ``length`` cached positions."""
-    return forward(cfg, B, 1, start=length, carried=True)
+    return forward(kind, cfg, B, 1, start=length, carried=True)

@@ -1,4 +1,5 @@
-"""Plain float32 reference of the benchmark's models.
+"""Plain float32 reference of the benchmark's models: the primitives the
+model kinds (``bench/kinds/``) build their layers from.
 
 Straightforward ``jax.numpy`` at ``Precision.HIGHEST``, written from the
 published descriptions and this benchmark's configuration files. It
@@ -17,6 +18,7 @@ imports nothing of the program. It reads the weight tree that
 - Mamba2: in projection, causal depthwise conv with SiLU, the SSD
   recurrence h_t = exp(a_t) h_{t-1} + B_t x_t dt_t, y_t = C_t h_t + D x_t,
   gated RMSNorm, out projection. The recurrence runs step by step.
+- The head: the tied embedding, or an untied ``lm_head``.
 
 ``Numerics`` decides how matrix products are computed: float32 at the
 highest precision, or (the control) with both operands rounded to fp8
@@ -55,8 +57,8 @@ class Numerics:
 
 @dataclasses.dataclass(frozen=True)
 class Spec:
-    """Sizes of one model, read from a configuration file."""
-    kind: str
+    """Sizes of one model, read from a configuration file by its kind's
+    ``spec_from_file``; a kind may subclass it with sizes of its own."""
     layers: int
     D: int
     V: int
@@ -78,30 +80,6 @@ class Spec:
     conv: int = 0
     aux_weight: float = 0.0
     z_weight: float = 0.0
-
-
-def spec_from_file(f: dict) -> Spec:
-    if f.get("model_type") == "granitemoe":
-        pr = f["program"]
-        return Spec(kind="moe", layers=f["num_hidden_layers"],
-                    D=f["hidden_size"], V=f["vocab_size"],
-                    eps=f["rms_norm_eps"], H=f["num_attention_heads"],
-                    Hkv=f["num_key_value_heads"],
-                    Dh=f["hidden_size"] // f["num_attention_heads"],
-                    theta=f["rope_theta"], E=f["num_local_experts"],
-                    K=f["num_experts_per_tok"], F=f["intermediate_size"],
-                    capacity_factor=pr["capacity_factor"],
-                    group=pr["moe_group"],
-                    steal_attempts=pr["moe_steal_attempts"],
-                    aux_weight=pr["router_aux_weight"],
-                    z_weight=pr["z_loss_weight"])
-    d_inner = f["expand"] * f["d_model"]
-    return Spec(kind="mamba2", layers=f["n_layer"], D=f["d_model"],
-                V=f["vocab_size"], eps=f["norm_eps"], d_inner=d_inner,
-                N=f["d_state"], P=f["headdim"], G=f["ngroups"],
-                conv=f["d_conv"], H=d_inner // f["headdim"],
-                aux_weight=f["program"]["router_aux_weight"],
-                z_weight=f["program"]["z_loss_weight"])
 
 
 def ring_table(E: int) -> np.ndarray:
@@ -258,7 +236,8 @@ def ssd(xdt, a, b, c, block: int = 64):
 
 def mamba(h, p, spec: Spec, num: Numerics):
     Bn, L, _ = h.shape
-    di, G, N, H, P, K = spec.d_inner, spec.G, spec.N, spec.H, spec.P, spec.conv
+    di, G, N, P, K = spec.d_inner, spec.G, spec.N, spec.P, spec.conv
+    H = di // P                    # SSD heads; spec.H counts attention heads
     proj = num.ein("bld,de->ble", h, p["in_proj"])
     z, xbc, dt = jnp.split(proj, [di, 2 * di + 2 * G * N], -1)
     w = p["conv_w"].astype(jnp.float32)
@@ -277,22 +256,23 @@ def mamba(h, p, spec: Spec, num: Numerics):
     return num.ein("ble,ed->bld", y, p["out_proj"])
 
 
-def layer(x, p, spec: Spec, num: Numerics, prompt_len: int, table):
-    """One layer on the residual stream x (B, L, D) f32 -> (x, aux)."""
-    p = jax.tree.map(lambda t: t.astype(jnp.float32), p)
-    if spec.kind == "mamba2":
-        return x + mamba(rmsnorm(x, p["ln1"], spec.eps), p["mix"], spec,
-                         num), jnp.zeros(())
-    x = x + attention(rmsnorm(x, p["ln1"], spec.eps), p["mix"], spec, num)
-    y, aux = moe(rmsnorm(x, p["ln2"], spec.eps), p["ffn"], spec, num,
-                 prompt_len, table)
-    return x + y, aux
+def tied_head(params):
+    """The (V, D) output projection of a model whose head is its
+    embedding."""
+    return params["embed"]
 
 
-def logits(x, params, spec: Spec, num: Numerics):
+def untied_head(params):
+    """The (V, D) output projection of a model with its own ``lm_head``
+    (stored (D, V))."""
+    return params["lm_head"].T
+
+
+def logits(x, params, spec: Spec, num: Numerics, head):
     h = rmsnorm(x, params["final_norm"], spec.eps)
-    return num.ein("bld,vd->blv", h, params["embed"])
+    return num.ein("bld,vd->blv", h, head(params))
 
 
-def layer_params(params, i: int):
-    return jax.tree.map(lambda t: t[i], params["blocks"][0])
+def slot_params(params, slot: int, i: int):
+    """Slot ``slot`` of the layer period, in its ``i``-th repeat."""
+    return jax.tree.map(lambda t: t[i], params["blocks"][slot])

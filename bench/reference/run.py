@@ -1,6 +1,7 @@
 """Drive the reference: logits of served tokens, and the first training
-steps with AdamW. Layer by layer or under remat, so that it fits one chip
-after the program's state is freed."""
+steps with AdamW. Every slot of the kind's layer period runs in order, in
+each repeat; layer by layer or under remat, so that it fits one chip after
+the program's state is freed."""
 
 from __future__ import annotations
 
@@ -12,47 +13,54 @@ import jax
 import jax.numpy as jnp
 
 from bench.check import leaf_norms
-from bench.reference.model import Numerics, Spec, layer, logits, rmsnorm
+from bench.reference.model import Numerics, Spec, logits, rmsnorm
 
 
-def serve_logits(params, spec: Spec, tokens: np.ndarray, prompt_len: int,
-                 table, num: Numerics = Numerics()) -> np.ndarray:
+def serve_logits(params, kind, spec: Spec, tokens: np.ndarray,
+                 prompt_len: int, table,
+                 num: Numerics = Numerics()) -> np.ndarray:
     """f32 logits (B, L - prompt_len + 1, V) at positions prompt_len-1 ..
     L-1 of tokens (B, L): the positions that produced the served tokens."""
+    slots = kind.slots(spec)
     with jax.default_matmul_precision("highest"):
         embed = jax.jit(lambda e, t: e[t].astype(jnp.float32))
-        step = jax.jit(lambda x, blocks, i: layer(
+        steps = [jax.jit(lambda x, blocks, i, slot=slot: slot(
             x, jax.tree.map(lambda t: jax.lax.dynamic_index_in_dim(
                 t, i, keepdims=False), blocks), spec, num, prompt_len,
-            table)[0])
-        head = jax.jit(lambda x, fn, e: logits(
-            x[:, prompt_len - 1:], {"final_norm": fn, "embed": e}, spec, num))
+            table)[0]) for slot in slots]
+        head = jax.jit(lambda x, p: logits(
+            x[:, prompt_len - 1:], p, spec, num, kind.head))
         x = embed(params["embed"], jnp.asarray(tokens))
-        for i in range(spec.layers):
-            x = step(x, params["blocks"][0], i)
-        return np.asarray(head(x, params["final_norm"], params["embed"]))
+        for i in range(spec.layers // len(slots)):
+            for step, blocks in zip(steps, params["blocks"]):
+                x = step(x, blocks, i)
+        return np.asarray(head(x, {k: v for k, v in params.items()
+                                   if k != "blocks"}))
 
 
-def loss_fn(params, spec: Spec, batch, table, num: Numerics):
+def loss_fn(params, kind, spec: Spec, batch, table, num: Numerics):
     """Cross-entropy over unmasked labels + aux + z-loss, f32."""
     tokens, labels = batch["tokens"], batch["labels"]
     B, S = tokens.shape
     x = params["embed"][tokens].astype(jnp.float32)
+    slots = kind.slots(spec)
 
     @jax.checkpoint
-    def body(carry, p):
+    def body(carry, ps):
         x, aux = carry
-        x, a = layer(x, p, spec, num, S, table)
-        return (x, aux + a), None
+        for slot, p in zip(slots, ps):
+            x, a = slot(x, p, spec, num, S, table)
+            aux = aux + a
+        return (x, aux), None
 
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros(())),
-                               params["blocks"][0])
+    (x, aux), _ = jax.lax.scan(body, (x, jnp.zeros(())), params["blocks"])
+    w = kind.head(params)
 
     @jax.checkpoint
     def row(args):
         xr, lr = args
         h = rmsnorm(xr, params["final_norm"], spec.eps)
-        lg = num.ein("sd,vd->sv", h, params["embed"])
+        lg = num.ein("sd,vd->sv", h, w)
         valid = lr >= 0
         logp = jax.nn.log_softmax(lg, -1)
         ll = jnp.take_along_axis(logp, jnp.where(valid, lr, 0)[:, None],
@@ -103,13 +111,14 @@ def adamw(params, grads, m, v, count: int, opt: dict):
     return pick(0), pick(1), pick(2)
 
 
-def train_steps(params, spec: Spec, batches: list[dict], opt: dict, table,
-                num: Numerics = Numerics()) -> dict:
+def train_steps(params, kind, spec: Spec, batches: list[dict], opt: dict,
+                table, num: Numerics = Numerics()) -> dict:
     """The reference's first len(batches) steps from ``params`` (stored
     dtype). Returns losses, the first step's gradient (and its norms by
     leaf) and the parameters' change norms by leaf after the last step."""
     with jax.default_matmul_precision("highest"):
-        vg = jax.value_and_grad(lambda p, b: loss_fn(p, spec, b, table, num))
+        vg = jax.value_and_grad(lambda p, b: loss_fn(p, kind, spec, b, table,
+                                                      num))
         # differentiate at f32 copies, so the gradients are f32
         grad_fn = jax.jit(lambda p, b: vg(
             jax.tree.map(lambda t: t.astype(jnp.float32), p), b))

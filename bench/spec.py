@@ -5,8 +5,9 @@ per-layer metric readers.
 configuration is ``bench/configs/<name>.json``, a traffic mix is
 ``bench/traffic/<name>.json`` (its ``driver`` key says whether it trains or
 serves), the limits of the cell's correctness check are
-``bench/limits/<cell>.json`` and a per-layer metric is
-``bench/metrics/<name>.py``. A later cell adds files and edits none.
+``bench/limits/<cell>.json``, a per-layer metric is
+``bench/metrics/<name>.py`` and a configuration's model kind is a module
+of ``bench/kinds/``. A later cell adds files and edits none.
 """
 
 from __future__ import annotations
@@ -15,6 +16,8 @@ import dataclasses
 import importlib.util
 import json
 from pathlib import Path
+
+from bench import kinds
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
@@ -56,43 +59,26 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
-# (file key, ArchConfig field) pairs that must agree with the program's
-# registered config: widths are never changed by the benchmark.
-_WIDTHS = {
-    "granitemoe": [("hidden_size", "d_model"), ("intermediate_size", "moe_d_ff"),
-                   ("num_attention_heads", "num_heads"),
-                   ("num_key_value_heads", "num_kv_heads"),
-                   ("num_local_experts", "moe_num_experts"),
-                   ("num_experts_per_tok", "moe_top_k")],
-    "mamba2": [("d_model", "d_model"), ("d_state", "ssm_state"),
-               ("d_conv", "ssm_conv"), ("expand", "ssm_expand"),
-               ("headdim", "ssm_head_dim"), ("ngroups", "ssm_groups")],
-}
-
-
 def arch_config(name: str):
     """The program's ``ArchConfig`` for configuration ``name``, as run."""
+    return program_config(config_file(name))
+
+
+def program_config(f: dict):
+    """The program's ``ArchConfig`` for configuration file ``f``: the
+    registered config of ``f["arch"]`` with the file's depth, vocabulary,
+    numerics and ``program`` settings. Widths are never changed: each of
+    the kind's ``WIDTHS`` must agree with the registered config."""
     from repro import configs
 
-    f = config_file(name)
     base = configs.get(f["arch"])
-    kind = f.get("model_type", "mamba2")
-    for key, field in _WIDTHS[kind]:
+    k = kinds.kind(f)
+    for key, field in k.WIDTHS:
         if f[key] != getattr(base, field):
-            raise SystemExit(f"{name}: {key}={f[key]} but the program's "
-                             f"{field} is {getattr(base, field)}")
-    prog = f.get("program", {})
-    if kind == "granitemoe":
-        over = dict(num_layers=f["num_hidden_layers"],
-                    vocab_size=f["vocab_size"], rope_theta=f["rope_theta"],
-                    norm_eps=f["rms_norm_eps"],
-                    tie_embeddings=f["tie_word_embeddings"],
-                    dtype=f["torch_dtype"])
-    else:
-        over = dict(num_layers=f["n_layer"], vocab_size=f["vocab_size"],
-                    ssm_chunk=f["chunk_size"], norm_eps=f["norm_eps"],
-                    tie_embeddings=f["tie_embeddings"], dtype=f["dtype"])
-    over.update(prog)
+            raise SystemExit(f"{f['arch']}: {key}={f[key]} but the "
+                             f"program's {field} is {getattr(base, field)}")
+    over = k.overrides(f)
+    over.update(f.get("program", {}))
     return dataclasses.replace(base, **over)
 
 

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 
 import small
-from bench import flops, spec, trace, weights
+from bench import flops, kinds, spec, trace, weights
 from bench.reference import model as ref_model
 from bench.traffic import gen
 
@@ -85,6 +85,10 @@ def test_serve_deck_holds_the_same_mix_every_cycle():
 
 # ---------------------------------------------------------------- counts
 
+def _kind(name: str):
+    return kinds.kind(spec.config_file(name))
+
+
 def test_flops_by_hand_for_one_granite_layer():
     cfg = spec.arch_config("granite-moe-1b-a400m")
     B, S, D, H, Hkv, Dh = 2, 16, 1024, 16, 8, 64
@@ -93,7 +97,7 @@ def test_flops_by_hand_for_one_granite_layer():
     assert att.flops == proj + 4 * B * H * Dh * (S * (S + 1) // 2)
     moe = flops.moe_experts(B * S, D, 512, 32, 8)
     assert moe.flops == 2 * B * S * D * 32 + 2 * B * S * 8 * 3 * D * 512
-    layer = (flops.forward(cfg, B, S).flops
+    layer = (flops.forward(_kind("granite-moe-1b-a400m"), cfg, B, S).flops
              - flops.head(B * S, D, cfg.vocab_size).flops
              - flops.rmsnorm(B * S, D).flops) / cfg.num_layers
     assert layer == att.flops + moe.flops + 2 * flops.rmsnorm(B * S, D).flops
@@ -108,7 +112,7 @@ def test_flops_by_hand_for_one_mamba2_layer():
             + 6 * B * S * di + 5 * B * S * H * N * P)
     got = flops.mamba_mixer(B, S, D, di, H, N, P, 1, K, False).flops
     assert got == want
-    layer = (flops.forward(cfg, B, S).flops
+    layer = (flops.forward(_kind("mamba2-1.3b"), cfg, B, S).flops
              - flops.head(B * S, D, cfg.vocab_size).flops
              - flops.rmsnorm(B * S, D).flops) / cfg.num_layers
     assert layer == want + flops.rmsnorm(B * S, D).flops
@@ -118,7 +122,7 @@ def test_decode_bytes_hold_every_weight_and_the_live_cache():
     cfg = spec.arch_config("granite-moe-1b-a400m")
     from repro.models import model as model_lib
     n_params = model_lib.param_count(cfg)
-    w = flops.decode_step(cfg, 8, 1000)
+    w = flops.decode_step(_kind("granite-moe-1b-a400m"), cfg, 8, 1000)
     kv = cfg.num_layers * 2 * 8 * 8 * 64 * 2 * 1001
     # every weight once (the router in f32), the cache, small activations
     assert n_params * 2 + kv < w.bytes < n_params * 2 + kv + 50e6
@@ -185,7 +189,8 @@ def test_every_cell_finds_its_files_by_name():
 
 
 def test_reference_imports_nothing_of_the_program():
-    for p in (ROOT / "bench" / "reference").glob("*.py"):
+    for p in [*(ROOT / "bench" / "reference").glob("*.py"),
+              *(ROOT / "bench" / "kinds").glob("*.py")]:
         text = p.read_text()
         assert "repro" not in text, p
 
@@ -195,7 +200,7 @@ def test_reference_imports_nothing_of_the_program():
 def test_reference_routing_matches_the_program_with_overflow():
     from repro.core.routing import RoutingConfig, route
 
-    s = ref_model.Spec(kind="moe", layers=1, D=8, V=8, eps=1e-6, E=32, K=8,
+    s = ref_model.Spec(layers=1, D=8, V=8, eps=1e-6, E=32, K=8,
                        capacity_factor=0.3, steal_attempts=2)
     table = ref_model.torus_table(32)
     logits = jax.random.normal(jax.random.PRNGKey(3), (256, 32)) * 2
@@ -210,10 +215,11 @@ def test_reference_routing_matches_the_program_with_overflow():
 
 
 def test_torus_table_is_the_programs_nearest_victim_order():
-    from bench.drivers.train import steal_table
+    from repro.launch.mesh import line_steal_table
 
     cfg = spec.arch_config("granite-moe-1b-a400m-l6")
-    assert (steal_table(cfg) == ref_model.torus_table(32)).all()
+    got = line_steal_table(cfg.moe_num_experts, cfg.moe_steal_policy)
+    assert (got == ref_model.torus_table(32)).all()
 
 
 @pytest.mark.parametrize("name", ["granite-moe-1b-a400m-l6",
@@ -222,20 +228,17 @@ def test_reference_forward_matches_the_program(name):
     from repro.models import model as model_lib
 
     cfg, f = small.configs(name)
-    s = ref_model.spec_from_file(f)
-    params = weights.make_init(weights.layout(cfg))(weights.seed_key(11))
+    kind = kinds.kind(f)
+    s = kind.spec_from_file(f)
+    params = weights.make_init(weights.layout(cfg), kind)(
+        weights.seed_key(11))
     tokens = jnp.asarray(gen.train_batch(small.mix("train-zipf-8x1024"),
                                          cfg.vocab_size, 11, 0)["tokens"])
-    table = ref_model.torus_table(s.E) if s.kind == "moe" else None
+    table = kind.train_table(s)
     with jax.default_matmul_precision("highest"):
         want, _ = model_lib.forward(params, cfg, tokens=tokens,
                                     steal_table=table)
-        x = params["embed"][tokens].astype(jnp.float32)
-        for i in range(s.layers):
-            x, _ = ref_model.layer(x, ref_model.layer_params(params, i), s,
-                                   ref_model.Numerics(), tokens.shape[1],
-                                   table)
-        got = ref_model.logits(x, params, s, ref_model.Numerics())
+        got = small.reference_logits(params, kind, s, tokens, table)
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
 

@@ -4,8 +4,10 @@ The benchmark makes the weights itself, so that the reference can make
 them again from the seed and take nothing the program has made. Only the
 tree's layout (names, shapes, dtypes) is taken from the program, from
 ``jax.eval_shape`` of its own init; every value is drawn here by the rule
-for the leaf's name. A leaf name without a rule is an error, so a layout
-change in the program cannot pass unnoticed.
+for the leaf's name: the rules here, and those a model kind adds for
+names of its own (``WEIGHTS`` of its module in ``bench/kinds/``). A leaf
+name without a rule is an error, so a layout change in the program cannot
+pass unnoticed.
 """
 
 from __future__ import annotations
@@ -28,7 +30,7 @@ def seed_key(seed: int):
     return jax.random.fold_in(k, np.uint32(s >> 32))
 
 
-def _leaf(name: str, key, shape, dtype):
+def _leaf(name: str, key, shape, dtype, rules: dict):
     n = jax.random.normal(key, shape, jnp.float32)
     if name == "embed":
         v = n * 0.02
@@ -48,6 +50,8 @@ def _leaf(name: str, key, shape, dtype):
         v = dt + jnp.log(-jnp.expm1(-dt))      # inverse softplus
     elif name == "D_skip":
         v = 1.0 + 0.1 * n
+    elif name in rules:
+        v = rules[name](key, shape)
     else:
         raise ValueError(f"no rule to draw weight leaf {name!r}")
     return v.astype(dtype)
@@ -65,14 +69,16 @@ def layout(cfg):
                           jax.random.PRNGKey(0))
 
 
-def make_init(abstract):
-    """A jitted ``init(key) -> params`` for the tree ``abstract``."""
+def make_init(abstract, kind):
+    """A jitted ``init(key) -> params`` for the tree ``abstract`` of a model
+    of ``kind``."""
+    rules = getattr(kind, "WEIGHTS", {})
     flat, treedef = jax.tree_util.tree_flatten_with_path(abstract)
 
     @jax.jit
     def init(key):
         keys = jax.random.split(key, len(flat))
-        leaves = [_leaf(_name(p), k, s.shape, s.dtype)
+        leaves = [_leaf(_name(p), k, s.shape, s.dtype, rules)
                   for (p, s), k in zip(flat, keys)]
         return jax.tree_util.tree_unflatten(treedef, leaves)
 
